@@ -173,4 +173,6 @@ def main(argv=None, mods: dict | None = None):
 
 
 if __name__ == "__main__":
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     sys.exit(main())

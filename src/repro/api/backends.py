@@ -78,7 +78,9 @@ def eval_linear_ct_op(n, vals: dict, p: TFHEParams):
     if n.op == "linear":
         W = jnp.asarray(np.asarray(n.attrs["W"], np.int64))
         x = vals[n.inputs[0]]                      # (in, big_n+1)
-        y = jnp.einsum("io,id->od", W.astype(U64), x)
+        # wraparound multiply-reduce, not a dot: no uint64 dot on TPU
+        y = jnp.sum(W.astype(U64)[:, :, None] * x[:, None, :], axis=0,
+                    dtype=U64)
         if n.attrs.get("bias") is not None:
             b = torus.encode(jnp.asarray(
                 np.asarray(n.attrs["bias"], np.int64).reshape(-1)

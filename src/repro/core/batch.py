@@ -4,7 +4,7 @@ Taurus's BRU round-robins 12 ciphertexts through one wide FFT pipeline so
 each BSK chunk streamed from HBM is consumed by ALL in-flight ciphertexts.
 On TPU the same insight is a BATCH dimension: one blind-rotation iteration
 loads bsk_f[i] once and applies it to the whole ciphertext batch via a
-single einsum (MXU-shaped, transform-domain).  Arithmetic intensity on the
+single transform-domain MAC.  Arithmetic intensity on the
 BSK stream scales linearly with the batch size, exactly the paper's Fig. 13
 bandwidth argument.
 
@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import decompose as dec, fft, ggsw, glwe, lwe
+from repro.core import ggsw, glwe, lwe
 from repro.core.params import TFHEParams
 
 U64 = jnp.uint64
@@ -30,26 +30,12 @@ def rotate_batch(cts: jax.Array, rs: jax.Array, N: int) -> jax.Array:
     return jax.vmap(lambda c, r: glwe.rotate(c, r, N))(cts, rs)
 
 
-def external_product_batch(ggsw_f: jax.Array, glwe_cts: jax.Array,
-                           base_log: int, level: int) -> jax.Array:
-    """One GGSW (fourier) applied to a BATCH of GLWEs — the key-reuse MAC.
-
-    ggsw_f: (k+1, level, k+1, N/2) complex — loaded ONCE.
-    glwe_cts: (B, k+1, N) uint64.
-    """
-    digits = dec.decompose(glwe_cts, base_log, level)   # (B, k+1, N, level)
-    digits = jnp.moveaxis(digits, -1, -2)               # (B, k+1, level, N)
-    dig_f = fft.forward(digits)                         # (B, k+1, level, N/2)
-    out_f = jnp.einsum("bulf,ulcf->bcf", dig_f, ggsw_f)
-    return fft.inverse_torus(out_f)
-
-
 def blind_rotate_batch(lut_glwes: jax.Array, ms_cts: jax.Array,
                        bsk_f: jax.Array, params: TFHEParams) -> jax.Array:
     """Batched blind rotation.
 
     lut_glwes: (B, k+1, N); ms_cts: (B, n+1) mod-switched to [0, 2N);
-    bsk_f: (n, k+1, level, k+1, N/2) — scanned once, shared across batch.
+    bsk_f: (n, 2, J, K, N/2) planes — scanned once, shared across batch.
     """
     N = params.N
     a, b = ms_cts[:, :-1], ms_cts[:, -1]
@@ -59,7 +45,8 @@ def blind_rotate_batch(lut_glwes: jax.Array, ms_cts: jax.Array,
         a_i, bsk_i = inp                                # a_i: (B,)
         rotated = rotate_batch(acc, a_i, N)
         diff = rotated - acc
-        acc = acc + external_product_batch(
+        # one GGSW applied to the whole batch: the key-reuse MAC
+        acc = acc + ggsw.external_product_fourier(
             bsk_i, diff, params.pbs_base_log, params.pbs_level
         )
         return acc, None
@@ -70,7 +57,7 @@ def blind_rotate_batch(lut_glwes: jax.Array, ms_cts: jax.Array,
 
 def keyswitch_batch(big_cts: jax.Array, ksk: jax.Array,
                     params: TFHEParams) -> jax.Array:
-    """(B, k*N+1) -> (B, n+1); a single wraparound int matmul (LPU)."""
+    """(B, k*N+1) -> (B, n+1); one wraparound multiply-reduce (LPU)."""
     return lwe.keyswitch(big_cts, ksk, params.ks_base_log, params.ks_level)
 
 

@@ -11,13 +11,16 @@ The engine is the execution backend for `repro.compiler` schedules and
 the unit benchmarks in `benchmarks/`.
 
 Kernel backends: `kernel_backend="reference"` (default) runs the jax
-reference PBS in `repro.core.batch`; `"pallas"` runs the fused Pallas
-engine room (`repro.kernels.fused_pbs`) — same KS-first pipeline, but
-the FFT / external-product / keyswitch stages execute as Pallas kernels
-against a `FusedPbsPack` of resident transform-domain key operands
-(built lazily on first `lut_batch`, reused across every round — the
-paper's key-reuse strategy).  Both backends are decrypt-identical; the
-keyswitch stage is bit-identical.
+PBS in `repro.core.batch` — float64 re/im planes and uint64 integer
+arithmetic only, the engine room XLA:TPU compiles and the one every
+platform runs; `"pallas"` runs the fused Pallas engine room
+(`repro.kernels.fused_pbs`) — same KS-first pipeline, but the FFT /
+external-product / keyswitch stages execute as Pallas kernels against a
+`FusedPbsPack` of resident transform-domain key operands (built lazily
+on first `lut_batch`, reused across every round — the paper's key-reuse
+strategy).  Both backends are decrypt-identical; the keyswitch stage is
+bit-identical.  The pallas room runs in interpret mode on CPU only and
+is refused on a TPU (see `ConfigError`).
 """
 from __future__ import annotations
 
@@ -26,7 +29,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.core import batch as batch_mod, glwe, lwe, torus
 from repro.core.params import TFHEParams
@@ -47,7 +51,11 @@ class ConfigError(ValueError):
     pallas + mesh is NOT supported: the fused kernels run per-device.
     The sharded `ServeRuntime` routes around this — a multi-device shard
     requesting the pallas backend gets a single-device engine instead of
-    raising here (see `repro.serve.shard.build_shards`)."""
+    raising here (see `repro.serve.shard.build_shards`).
+
+    pallas on a TPU is NOT supported either: Mosaic has no float64, and
+    float32 transform planes lack the precision of the 64-bit torus
+    until the split fixed-point planes of ROADMAP S2 land."""
 
 
 def validate_lut_tables(cts: jax.Array, tables, params: TFHEParams):
@@ -85,14 +93,27 @@ class TaurusEngine:
     # auto-attach, so a shared engine never pollutes baseline waves.
     telemetry: Optional[object] = None
     # "reference" = jax PBS in repro.core.batch; "pallas" = fused kernel
-    # path in repro.kernels.fused_pbs (interpret mode on CPU).
+    # path in repro.kernels.fused_pbs (interpret mode, CPU only).
     kernel_backend: str = "reference"
+    # single-device engines: the device that holds the keys and runs
+    # every round (None = wherever the keys already are).  A sharded
+    # ServeRuntime pins each shard's engine to its own device.
+    device: Optional[jax.Device] = None
 
     def __post_init__(self):
         if self.kernel_backend not in ("reference", "pallas"):
             raise ValueError(
                 f"kernel_backend must be 'reference' or 'pallas', "
                 f"got {self.kernel_backend!r}")
+        if self.kernel_backend == "pallas" and jax.default_backend() == "tpu":
+            raise ConfigError(
+                "kernel_backend='pallas' is not supported on TPU: Mosaic "
+                "has no float64, and float32 transform planes lack the "
+                "precision of the 64-bit torus until the split fixed-point "
+                "planes of ROADMAP S2 land. Use the reference backend, "
+                "which runs float64 planes through XLA.")
+        if self.mesh is not None and self.device is not None:
+            raise ConfigError("pass mesh OR device, not both")
         if self.kernel_backend == "pallas" and self.mesh is not None:
             raise ConfigError(
                 "kernel_backend='pallas' + mesh is not a supported engine "
@@ -102,6 +123,19 @@ class TaurusEngine:
                 "backend for multi-cluster meshes, or drop the mesh for "
                 "the pallas engine room (the sharded ServeRuntime does "
                 "the latter automatically).")
+        if self.mesh is not None:
+            # replicate the keys once, not on every sharded round
+            repl = NamedSharding(self.mesh, P())
+            self.bsk_f, self.ksk = jax.device_put((self.bsk_f, self.ksk), repl)
+        elif self.device is not None:
+            self.bsk_f, self.ksk = self.place(self.bsk_f, self.ksk)
+
+    def place(self, *arrays):
+        """Move round inputs onto the engine's pinned device (no-op for
+        an unpinned engine)."""
+        if self.device is None:
+            return arrays
+        return jax.device_put(arrays, SingleDeviceSharding(self.device))
 
     # -- derived -----------------------------------------------------------
     @property
@@ -126,6 +160,22 @@ class TaurusEngine:
             pack = self._fused_pack = FusedPbsPack.build(
                 self.bsk_f, self.ksk, self.params)
         return pack
+
+    @property
+    def _mesh_pbs(self):
+        """`pbs_batch` jitted once for the mesh: rows sharded over the
+        data axis, keys replicated."""
+        fn = getattr(self, "_mesh_pbs_fn", None)
+        if fn is None:
+            data_sh = NamedSharding(self.mesh, P(self.data_axis))
+            repl = NamedSharding(self.mesh, P())
+            fn = self._mesh_pbs_fn = jax.jit(
+                batch_mod.pbs_batch.__wrapped__,
+                static_argnames=("params",),
+                in_shardings=(data_sh, data_sh, repl, repl),
+                out_shardings=data_sh,
+            )
+        return fn
 
     @property
     def n_clusters(self) -> int:
@@ -180,6 +230,7 @@ class TaurusEngine:
         if pad:
             cts = jnp.concatenate([cts, cts[:pad]], axis=0)
             lut_polys = jnp.concatenate([lut_polys, lut_polys[:pad]], axis=0)
+        cts, lut_polys = self.place(cts, lut_polys)
         tel = self.telemetry
         span = (tel.span("lut_batch", cat="engine", rows=B, padded=pad)
                 if tel is not None else None)
@@ -192,15 +243,18 @@ class TaurusEngine:
                 else:
                     out = batch_mod.pbs_batch(cts, lut_polys, self.bsk_f, self.ksk, self.params)
             else:
+                # rows may arrive committed to another layout (a previous
+                # round's sharded output): reshard them onto the data axis
                 data_sh = NamedSharding(self.mesh, P(self.data_axis))
-                repl = NamedSharding(self.mesh, P())
-                fn = jax.jit(
-                    batch_mod.pbs_batch,
-                    static_argnames=("params",),
-                    in_shardings=(data_sh, data_sh, repl, repl),
-                    out_shardings=data_sh,
-                )
-                out = fn(cts, lut_polys, self.bsk_f, self.ksk, self.params)
+                cts, lut_polys = jax.device_put((cts, lut_polys), data_sh)
+                out = self._mesh_pbs(cts, lut_polys, self.bsk_f, self.ksk,
+                                     self.params)
+                # hand the round back on one device: request threads
+                # then run single-device ops only, never concurrent
+                # multi-device programs whose collectives could pair up
+                # in different orders on different devices and deadlock
+                out = jax.device_put(
+                    out, SingleDeviceSharding(self.mesh.devices.flat[0]))
         finally:
             if span is not None:
                 span.__exit__(None, None, None)
@@ -225,6 +279,7 @@ class TaurusEngine:
                 "keyswitch/lut_batch_small need a single-device engine "
                 "(supports_ks_split) — the mesh path dispatches full PBS "
                 "rounds only")
+        (big_cts,) = self.place(big_cts)
         if self.kernel_backend == "pallas":
             return self.fused_pack.keyswitch(big_cts)
         return batch_mod.keyswitch_batch_jit(big_cts, self.ksk, self.params)
@@ -246,6 +301,7 @@ class TaurusEngine:
                 f"lut_batch_small: {B} ciphertexts but "
                 f"{lut_polys.shape[0]} LUT polynomials — counts must "
                 f"match per batch row")
+        small_cts, lut_polys = self.place(small_cts, lut_polys)
         tel = self.telemetry
         span = (tel.span("lut_batch_small", cat="engine", rows=B)
                 if tel is not None else None)

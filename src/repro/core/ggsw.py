@@ -7,9 +7,9 @@ The external product  GGSW ⊡ GLWE -> GLWE  (paper Fig. 4b) is a
 vector-matrix product over polynomials in the transform domain.  Its
 Pallas incarnation, `repro.kernels.external_product`, runs inside the
 engine's fused PBS path (`TaurusEngine(kernel_backend="pallas")` ->
-`repro.kernels.fused_pbs`) against a resident plane-layout BSK built
-once from `bsk_to_fourier`'s output; this module is the reference path
-and the differential-test oracle.
+`repro.kernels.fused_pbs`) against the same plane-layout BSK that
+`bsk_to_fourier` builds; this module is the engine room every platform
+runs by default and the differential-test oracle.
 """
 from __future__ import annotations
 
@@ -54,26 +54,39 @@ def bsk_gen(key: jax.Array, lwe_sk: jax.Array, glwe_sk: jax.Array,
     return jax.vmap(f)(keys, lwe_sk)
 
 
+def ggsw_to_fourier(ggsw_ct: jax.Array) -> jax.Array:
+    """One GGSW (k+1, level, k+1, N) uint64 -> plane layout (2, J, K, M).
+
+    J = (k+1)*level decomposition rows in the order
+    `external_product_fourier` contracts over, K = k+1 output polys,
+    M = N/2 transform-domain coefficients as float64 (re, im) planes.
+    """
+    kp1, level, _, N = ggsw_ct.shape
+    f = fft.forward(ggsw_ct).reshape(kp1 * level, kp1, 2, N // 2)
+    return jnp.moveaxis(f, 2, 0)
+
+
 def bsk_to_fourier(bsk: jax.Array) -> jax.Array:
-    """Pre-transform the BSK once (complex128 (n, k+1, level, k+1, N/2)).
+    """Pre-transform the BSK once: (n, 2, J, K, M) float64 planes.
 
     This is the stream the paper's BRU reads from HBM; in the batched
-    engine it is the reused operand (key-reuse strategy, §III-B).
+    engine it is the reused operand (key-reuse strategy, §III-B).  The
+    Pallas engine room reads the same layout (`kernels.fused_pbs`).
     """
-    return fft.forward(bsk)
+    return jax.vmap(ggsw_to_fourier)(bsk)
 
 
 def external_product_fourier(ggsw_f: jax.Array, glwe_ct: jax.Array,
                              base_log: int, level: int) -> jax.Array:
-    """GGSW (fourier, (k+1, level, k+1, N/2)) ⊡ GLWE ((k+1, N)) -> GLWE.
+    """GGSW (planes, (2, J, K, M)) ⊡ GLWE ((k+1, N)) -> GLWE.
 
     Batched over leading axes of `glwe_ct`.
     """
+    *lead, kp1, N = glwe_ct.shape
     digits = dec.decompose(glwe_ct, base_log, level)     # (..., k+1, N, level)
     digits = jnp.moveaxis(digits, -1, -2)                # (..., k+1, level, N)
-    dig_f = fft.forward(digits)                          # (..., k+1, level, N/2)
-    out_f = jnp.einsum("...ulf,ulcf->...cf", dig_f, ggsw_f)
-    return fft.inverse_torus(out_f)
+    dig_f = fft.forward(digits.reshape(*lead, kp1 * level, N))
+    return fft.inverse_torus(fft.mac(dig_f, ggsw_f))     # (..., k+1, N)
 
 
 def cmux_fourier(ggsw_f: jax.Array, ct0: jax.Array, ct1: jax.Array,

@@ -34,18 +34,14 @@ def encrypt(key: jax.Array, sk: jax.Array, msg_poly: jax.Array, std: float) -> j
     ka, ke = jax.random.split(key)
     a = torus.random_torus(ka, shape + (k, N))
     e = torus.gaussian_noise(ke, shape + (N,), std)
-    # b = sum_i a_i * s_i + m + e  (negacyclic products)
-    prod = fft.inverse_torus(
-        (fft.forward(a) * fft.forward(sk)).sum(axis=-2)
-    )
-    b = prod + msg_poly + e
+    # b = sum_i a_i * s_i + m + e  (negacyclic products, exact)
+    b = fft.negacyclic_mul_exact(sk, a) + msg_poly + e
     return jnp.concatenate([a, b[..., None, :]], axis=-2)
 
 
 def decrypt_phase(sk: jax.Array, ct: jax.Array) -> jax.Array:
     a, b = ct[..., :-1, :], ct[..., -1, :]
-    prod = fft.inverse_torus((fft.forward(a) * fft.forward(sk)).sum(axis=-2))
-    return b - prod
+    return b - fft.negacyclic_mul_exact(sk, a)
 
 
 def trivial(msg_poly: jax.Array, k: int) -> jax.Array:

@@ -4,10 +4,10 @@ Ciphertext layout: (..., n+1) uint64 = [a_0 .. a_{n-1}, b].
 All functions are batched over leading axes.
 
 Key-switching here is the paper's most expensive LPU op.  The Pallas
-uint32-limb version in `repro.kernels.keyswitch` is wired into the PBS
-hot path via `TaurusEngine(kernel_backend="pallas")`
-(`repro.kernels.fused_pbs.keyswitch_fused`) and is BIT-IDENTICAL to
-`keyswitch` below — the limb MAC is exact mod 2^64, pinned by
+uint32-limb version in `repro.kernels.keyswitch`
+(`repro.kernels.fused_pbs.keyswitch_fused`, the
+`TaurusEngine(kernel_backend="pallas")` path) is BIT-IDENTICAL to
+`keyswitch` below — both are exact mod 2^64, pinned by
 `tests/test_kernels.py`.
 """
 from __future__ import annotations
@@ -98,11 +98,11 @@ def keyswitch(ct: jax.Array, ksk: jax.Array, base_log: int, level: int) -> jax.A
     n_to = ksk.shape[-1] - 1
     a, b = ct[..., :-1], ct[..., -1]
     digits = dec.decompose(a, base_log, level)      # (..., n_from, level) int64
-    # out = (0, b) - sum_{i,l} digit * KSK[i,l]
-    acc = jnp.einsum(
-        "...il,ilj->...j",
-        digits.astype(U64), ksk,
-    ).astype(U64)  # wraparound dot; digit cast is two's-complement-correct
+    # out = (0, b) - sum_{i,l} digit * KSK[i,l], as a wraparound multiply
+    # and reduce (the digit cast is two's-complement-correct).  Not a
+    # dot: XLA:TPU has no uint64 dot, but fuses this into one reduction.
+    acc = jnp.sum(digits.astype(U64)[..., None] * ksk, axis=(-3, -2),
+                  dtype=U64)
     out = -acc
     out = out.at[..., -1].add(b)
     return out

@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import torus, fft, glwe, ggsw, lwe
+from repro.core import torus, glwe, ggsw, lwe
 from repro.core.params import TFHEParams
 
 U64 = jnp.uint64
@@ -31,7 +31,7 @@ def blind_rotate(lut_glwe: jax.Array, lwe_ct_mod: jax.Array,
 
     lut_glwe: (k+1, N) trivial/encrypted GLWE holding the LUT.
     lwe_ct_mod: (n+1,) uint64 values already mod-switched into [0, 2N).
-    bsk_f: (n, k+1, level, k+1, N/2) fourier BSK.
+    bsk_f: (n, 2, J, K, N/2) plane-layout fourier BSK.
     """
     N = params.N
     a, b = lwe_ct_mod[:-1], lwe_ct_mod[-1]
@@ -65,6 +65,21 @@ def pbs(big_ct: jax.Array, lut_poly: jax.Array, bsk_f: jax.Array,
     return glwe.sample_extract(acc)
 
 
+@functools.partial(jax.jit, static_argnames=("params",))
+def _keygen(key: jax.Array, params: TFHEParams):
+    """Secret keys and evaluation keys, as one program on the default
+    device: (lwe_sk, glwe_sk, big_sk, bsk_f, ksk)."""
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    lwe_sk = lwe.keygen(k1, params.n)
+    glwe_sk = glwe.keygen(k2, params.k, params.N)
+    big_sk = glwe.flatten_key(glwe_sk)
+    bsk = ggsw.bsk_gen(k3, lwe_sk, glwe_sk, params)
+    bsk_f = ggsw.bsk_to_fourier(bsk)
+    ksk = lwe.ksk_gen(k4, big_sk, lwe_sk,
+                      params.ks_base_log, params.ks_level, params.lwe_std)
+    return lwe_sk, glwe_sk, big_sk, bsk_f, ksk
+
+
 @dataclasses.dataclass
 class TFHEContext:
     """Client-side key material + encode/encrypt helpers (Fig. 1 client)."""
@@ -72,20 +87,12 @@ class TFHEContext:
     lwe_sk: jax.Array      # small key (n,)
     glwe_sk: jax.Array     # (k, N)
     big_sk: jax.Array      # flattened GLWE key (k*N,)
-    bsk_f: jax.Array       # fourier bootstrapping key (server/eval key)
+    bsk_f: jax.Array       # fourier bootstrapping key, f64 planes (eval key)
     ksk: jax.Array         # key-switching key big->small (server/eval key)
 
     @classmethod
     def create(cls, key: jax.Array, params: TFHEParams) -> "TFHEContext":
-        k1, k2, k3, k4 = jax.random.split(key, 4)
-        lwe_sk = lwe.keygen(k1, params.n)
-        glwe_sk = glwe.keygen(k2, params.k, params.N)
-        big_sk = glwe.flatten_key(glwe_sk)
-        bsk = ggsw.bsk_gen(k3, lwe_sk, glwe_sk, params)
-        bsk_f = ggsw.bsk_to_fourier(bsk)
-        ksk = lwe.ksk_gen(k4, big_sk, lwe_sk,
-                          params.ks_base_log, params.ks_level, params.lwe_std)
-        return cls(params, lwe_sk, glwe_sk, big_sk, bsk_f, ksk)
+        return cls(params, *_keygen(key, params))
 
     # -- client ops ------------------------------------------------------
     def encrypt(self, key: jax.Array, msg) -> jax.Array:

@@ -12,5 +12,14 @@
 
 Each kernel ships jit wrappers in `ops.py` and a pure-jnp oracle in
 `ref.py`; tests sweep shapes/dtypes in interpret mode and grade the
-fused path differentially against the reference engine.
+fused path differentially against the reference engine, and
+`tests/test_tpu_compile.py` compiles each kernel for a described v5e.
 """
+import jax
+
+
+def interpret_mode() -> bool:
+    """Pallas interpret mode follows the platform: on for CPU, which has
+    no Mosaic compiler, and off on a TPU, where the kernels compile for
+    real.  Every kernel entry resolves `interpret=None` through this."""
+    return jax.default_backend() == "cpu"

@@ -25,44 +25,57 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 
 def _kernel(dig_ref, bsk_ref, o_ref):
-    dr = dig_ref[:, 0]            # (B, J, Fb)
-    di = dig_ref[:, 1]
-    wr = bsk_ref[0]               # (J, K, Fb)
-    wi = bsk_ref[1]
-    # out[b,k,f] = sum_j d[b,j,f] * w[j,k,f]
-    outr = jnp.einsum("bjf,jkf->bkf", dr, wr) - jnp.einsum("bjf,jkf->bkf", di, wi)
-    outi = jnp.einsum("bjf,jkf->bkf", dr, wi) + jnp.einsum("bjf,jkf->bkf", di, wr)
-    o_ref[:, 0] = outr
-    o_ref[:, 1] = outi
+    # dig (2, J, B, Fb), bsk (2, J, K, Fb), out (2, K, B, Fb): every
+    # operand below is a (B, Fb) tile or a (1, Fb) key row broadcast
+    # over the batch, so the MAC is J*K elementwise multiply-adds
+    J, K = bsk_ref.shape[1], bsk_ref.shape[2]
+    for k in range(K):
+        out_r = out_i = None
+        for j in range(J):
+            dr, di = dig_ref[0, j], dig_ref[1, j]
+            wr = bsk_ref[0, j, pl.ds(k, 1), :]
+            wi = bsk_ref[1, j, pl.ds(k, 1), :]
+            r, i = dr * wr - di * wi, dr * wi + di * wr
+            out_r = r if out_r is None else out_r + r
+            out_i = i if out_i is None else out_i + i
+        o_ref[0, k] = out_r
+        o_ref[1, k] = out_i
 
 
 @functools.partial(jax.jit, static_argnames=("block_f", "interpret", "dtype"))
 def external_product_mac(dig: jax.Array, bsk: jax.Array, *,
-                         block_f: int = 2048, interpret: bool = True,
+                         block_f: int = 512, interpret: bool | None = None,
                          dtype=jnp.float32) -> jax.Array:
     """dig (B,2,J,F), bsk (2,J,K,F) -> (B,2,K,F), stacked re/im planes.
 
     `dtype` selects the plane precision: f32 is the TPU-native mode; the
     fused engine path runs f64 planes (interpret mode) so the MAC error
     stays far below the scheme's noise budget on 64-bit torus operands.
+    The batch axis moves next to F outside the kernel, so each grid
+    step's tiles are (B, block_f) and fit VMEM at any J, K.
     """
     B, _, J, F = dig.shape
     _, _, K, _ = bsk.shape
     dtype = jnp.dtype(dtype)
     bf = min(block_f, F)
     assert F % bf == 0
-    return pl.pallas_call(
+    z = np.int32(0)  # int32 block indices, also under jax_enable_x64
+    out = pl.pallas_call(
         _kernel,
-        out_shape=jax.ShapeDtypeStruct((B, 2, K, F), dtype),
+        out_shape=jax.ShapeDtypeStruct((2, K, B, F), dtype),
         grid=(F // bf,),
         in_specs=[
-            pl.BlockSpec((B, 2, J, bf), lambda f: (0, 0, 0, f)),
-            pl.BlockSpec((2, J, K, bf), lambda f: (0, 0, 0, f)),
+            pl.BlockSpec((2, J, B, bf), lambda f: (z, z, z, f)),
+            pl.BlockSpec((2, J, K, bf), lambda f: (z, z, z, f)),
         ],
-        out_specs=pl.BlockSpec((B, 2, K, bf), lambda f: (0, 0, 0, f)),
-        interpret=interpret,
-    )(dig.astype(dtype), bsk.astype(dtype))
+        out_specs=pl.BlockSpec((2, K, B, bf), lambda f: (z, z, z, f)),
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(jnp.transpose(dig.astype(dtype), (1, 2, 0, 3)), bsk.astype(dtype))
+    return jnp.transpose(out, (2, 0, 1, 3))

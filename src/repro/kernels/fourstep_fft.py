@@ -24,6 +24,12 @@ Layout contract (matches `repro.core.fft` up to dtype):
     forward:  real coeffs (B, N) -> spectrum (B, 2, M), M = N/2,
               spectrum[m] = FFT_M(fold+twist(x))[m]
     inverse:  spectrum (B, 2, M) -> real coeffs (B, N)
+
+Tiling: the wrappers fold each polynomial into (2, R, C) planes and the
+spectrum's natural-order transpose happens outside the kernel, so every
+block is (ROWS, 2, R, C) with full-extent minor dims, and each grid
+step transforms ROWS = 8 polynomials (the batch is zero-padded up to a
+multiple of 8).  No in-kernel reshape or transpose.
 """
 from __future__ import annotations
 
@@ -34,32 +40,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core.fft import dft_constants, factor_m  # noqa: F401
+from repro.kernels import interpret_mode
 
-def factor_m(M: int) -> tuple[int, int]:
-    """Pick R*C = M mirroring the paper's 256x128 for M = 2^15."""
-    assert M & (M - 1) == 0 and M >= 4
-    lg = M.bit_length() - 1
-    r = min(256, 1 << ((lg + 1) // 2))
-    return r, M // r
+ROWS = 8  # polynomials per grid step: the sublane tile of the batch axis
 
 
-@functools.lru_cache(maxsize=32)
-def _constants(N: int, inverse: bool, dtype_name: str = "float32"):
-    """Precompute twist, DFT matrices, twiddles as stacked re/im planes."""
-    M = N // 2
-    R, C = factor_m(M)
-    j = np.arange(M)
-    twist = np.exp(1j * np.pi * j / N)                       # fold twist
-    dft_r = np.exp(-2j * np.pi * np.outer(np.arange(R), np.arange(R)) / R)
-    dft_c = np.exp(-2j * np.pi * np.outer(np.arange(C), np.arange(C)) / C)
-    tw = np.exp(-2j * np.pi * np.outer(np.arange(R), np.arange(C)) / M)
-    if inverse:
-        dft_r, dft_c, tw, twist = (
-            np.conj(dft_r) / R, np.conj(dft_c) / C, np.conj(tw), np.conj(twist))
-    # NB: cache plain numpy (never jnp) — a jnp constant created inside a
-    # jit trace is a Tracer and would leak through the lru_cache.
-    as_planes = lambda z: np.stack([z.real, z.imag]).astype(dtype_name)
-    return R, C, as_planes(twist), as_planes(dft_r), as_planes(dft_c), as_planes(tw)
+def _planes(N: int, inverse: bool, dtype):
+    """Kernel constants in the plane dtype: twist (2, R, C), DFT_R
+    (2, R, R), DFT_C (2, C, C), twiddle (2, R, C)."""
+    R, C, twist, dr, dc, tw = dft_constants(N, inverse)
+    cast = lambda a: a.astype(dtype)
+    return R, C, cast(twist.reshape(2, R, C)), cast(dr), cast(dc), cast(tw)
 
 
 def _cmatmul(ar, ai, br, bi, acc_dtype):
@@ -68,88 +60,85 @@ def _cmatmul(ar, ai, br, bi, acc_dtype):
     return dot(ar, br) - dot(ai, bi), dot(ar, bi) + dot(ai, br)
 
 
-def _fwd_kernel(x_ref, twist_ref, dr_ref, dc_ref, tw_ref, o_ref, *, R, C, M,
+def _cmul(ar, ai, br, bi):
+    return ar * br - ai * bi, ar * bi + ai * br
+
+
+def _fwd_kernel(x_ref, twist_ref, dr_ref, dc_ref, tw_ref, o_ref, *,
                 acc_dtype):
-    x = x_ref[0]                                   # (N,) real coeffs
-    # fold + twist: u = (x_lo + i x_hi) * twist
-    ur = x[:M] * twist_ref[0] - x[M:] * twist_ref[1]
-    ui = x[:M] * twist_ref[1] + x[M:] * twist_ref[0]
-    ar, ai = ur.reshape(R, C), ui.reshape(R, C)
-    # stage A (FFT-A analogue): column DFT via MXU
-    er, ei = _cmatmul(dr_ref[0], dr_ref[1], ar, ai, acc_dtype)
-    # twiddle (between-stage rotation)
-    br = er * tw_ref[0] - ei * tw_ref[1]
-    bi = er * tw_ref[1] + ei * tw_ref[0]
-    # stage B (FFT-B analogue): row DFT; transpose-of-output IS the
-    # paper's shutter transpose, folded into the store layout.
-    fr, fi = _cmatmul(br, bi, dc_ref[0].T, dc_ref[1].T, acc_dtype)
-    o_ref[0, 0] = fr.T.reshape(M)
-    o_ref[0, 1] = fi.T.reshape(M)
+    for b in range(ROWS):
+        # fold + twist: u = (x_lo + i x_hi) * twist, as (R, C) planes
+        ur, ui = _cmul(x_ref[b, 0], x_ref[b, 1], twist_ref[0], twist_ref[1])
+        # stage A (FFT-A analogue): column DFT via MXU
+        er, ei = _cmatmul(dr_ref[0], dr_ref[1], ur, ui, acc_dtype)
+        # twiddle (between-stage rotation)
+        br, bi = _cmul(er, ei, tw_ref[0], tw_ref[1])
+        # stage B (FFT-B analogue): row DFT; the paper's shutter
+        # transpose is the wrapper's store layout
+        fr, fi = _cmatmul(br, bi, dc_ref[0], dc_ref[1], acc_dtype)
+        o_ref[b, 0] = fr
+        o_ref[b, 1] = fi
 
 
-def _inv_kernel(s_ref, twist_ref, dr_ref, dc_ref, tw_ref, o_ref, *, R, C, M,
+def _inv_kernel(s_ref, twist_ref, dr_ref, dc_ref, tw_ref, o_ref, *,
                 acc_dtype):
-    sr = s_ref[0, 0].reshape(C, R).T               # undo output transpose
-    si = s_ref[0, 1].reshape(C, R).T
-    # inverse stage B
-    br, bi = _cmatmul(sr, si, dc_ref[0].T, dc_ref[1].T, acc_dtype)
-    # un-twiddle
-    er = br * tw_ref[0] - bi * tw_ref[1]
-    ei = br * tw_ref[1] + bi * tw_ref[0]
-    # inverse stage A
-    ar, ai = _cmatmul(dr_ref[0], dr_ref[1], er, ei, acc_dtype)
-    ur, ui = ar.reshape(M), ai.reshape(M)
-    # untwist + unfold
-    xr = ur * twist_ref[0] - ui * twist_ref[1]
-    xi = ur * twist_ref[1] + ui * twist_ref[0]
-    o_ref[0] = jnp.concatenate([xr, xi])
+    for b in range(ROWS):
+        # inverse stage B, un-twiddle, inverse stage A
+        br, bi = _cmatmul(s_ref[b, 0], s_ref[b, 1], dc_ref[0], dc_ref[1],
+                          acc_dtype)
+        er, ei = _cmul(br, bi, tw_ref[0], tw_ref[1])
+        ar, ai = _cmatmul(dr_ref[0], dr_ref[1], er, ei, acc_dtype)
+        # untwist; the wrapper unfolds (re, im) into coefficients
+        xr, xi = _cmul(ar, ai, twist_ref[0], twist_ref[1])
+        o_ref[b, 0] = xr
+        o_ref[b, 1] = xi
 
 
-def _const_specs(R, C, M):
-    full = lambda shape: pl.BlockSpec(shape, lambda b: (0,) * len(shape))
-    return [
-        full((2, M)),          # twist
-        full((2, R, R)),       # DFT_R
-        full((2, C, C)),       # DFT_C
-        full((2, R, C)),       # twiddle
-    ]
+def _call(kernel, planes, consts, interpret):
+    Bp, _, R, C = planes.shape
+    z = np.int32(0)  # int32 block indices, also under jax_enable_x64
+    rows = pl.BlockSpec((ROWS, 2, R, C), lambda b: (b, z, z, z))
+    full = lambda a: pl.BlockSpec(a.shape, lambda b: (z,) * a.ndim)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(planes.shape, planes.dtype),
+        grid=(Bp // ROWS,),
+        in_specs=[rows] + [full(c) for c in consts],
+        out_specs=rows,
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(planes, *consts)
+
+
+def _pad_rows(x: jax.Array) -> jax.Array:
+    pad = (-x.shape[0]) % ROWS
+    if not pad:
+        return x
+    return jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "dtype"))
-def fft_forward(x: jax.Array, *, interpret: bool = True,
+def fft_forward(x: jax.Array, *, interpret: bool | None = None,
                 dtype=jnp.float32) -> jax.Array:
     """Negacyclic forward transform: real (B, N) -> (B, 2, N/2) planes."""
     B, N = x.shape
     M = N // 2
-    R, C = factor_m(M)
     dtype = jnp.dtype(dtype)
-    _, _, twist, dr, dc, tw = _constants(N, False, dtype.name)
-    kernel = functools.partial(_fwd_kernel, R=R, C=C, M=M, acc_dtype=dtype)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((B, 2, M), dtype),
-        grid=(B,),
-        in_specs=[pl.BlockSpec((1, N), lambda b: (b, 0))] + _const_specs(R, C, M),
-        out_specs=pl.BlockSpec((1, 2, M), lambda b: (b, 0, 0)),
-        interpret=interpret,
-    )(x.astype(dtype), twist, dr, dc, tw)
+    R, C, *consts = _planes(N, False, dtype)
+    kernel = functools.partial(_fwd_kernel, acc_dtype=dtype)
+    planes = _pad_rows(x.astype(dtype)).reshape(-1, 2, R, C)
+    spec = _call(kernel, planes, consts, interpret)      # [k1, k2] order
+    return jnp.swapaxes(spec, -1, -2).reshape(-1, 2, M)[:B]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "dtype"))
-def fft_inverse(spec: jax.Array, *, interpret: bool = True,
+def fft_inverse(spec: jax.Array, *, interpret: bool | None = None,
                 dtype=jnp.float32) -> jax.Array:
     """Inverse: (B, 2, M) planes -> real coeffs (B, 2M)."""
     B, _, M = spec.shape
     N = 2 * M
-    R, C = factor_m(M)
     dtype = jnp.dtype(dtype)
-    _, _, twist, dr, dc, tw = _constants(N, True, dtype.name)
-    kernel = functools.partial(_inv_kernel, R=R, C=C, M=M, acc_dtype=dtype)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((B, N), dtype),
-        grid=(B,),
-        in_specs=[pl.BlockSpec((1, 2, M), lambda b: (b, 0, 0))] + _const_specs(R, C, M),
-        out_specs=pl.BlockSpec((1, N), lambda b: (b, 0)),
-        interpret=interpret,
-    )(spec.astype(dtype), twist, dr, dc, tw)
+    R, C, *consts = _planes(N, True, dtype)
+    kernel = functools.partial(_inv_kernel, acc_dtype=dtype)
+    planes = jnp.swapaxes(
+        _pad_rows(spec.astype(dtype)).reshape(-1, 2, C, R), -1, -2)
+    return _call(kernel, planes, consts, interpret).reshape(-1, N)[:B]

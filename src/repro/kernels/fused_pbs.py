@@ -14,21 +14,24 @@ explicit as RESIDENT operands:
                   to torus coefficients.
     extract       `repro.core.glwe.sample_extract` (LPU layout work).
 
-`FusedPbsPack` is the residency contract: the Fourier BSK is decomposed
-into the kernels' stacked re/im plane layout ONCE per key, and the KSK
-is limb-split into (hi, lo) uint32 planes ONCE — every subsequent
+`FusedPbsPack` is the residency contract: the Fourier BSK (already in
+the kernels' stacked re/im plane layout) is cast to the plane dtype
+ONCE per key, and the KSK is limb-split into (hi, lo) uint32 planes
+ONCE — every subsequent
 `lut_batch` round of every fused wave consumes the same device arrays.
 That is the paper's §III-B round-robin key reuse: arithmetic intensity
 on the key stream scales with the fused batch size because the operand
 never has to be re-derived (and on hardware, re-fetched) per round.
 
-Precision: the transform-domain planes default to f64.  Interpret mode
-(this container) executes f64 natively and the 64-bit torus needs it —
-an f32-only transform would put ~2^60+ of error into the accumulator,
-voiding decryption.  On a real TPU the same kernels run f32 planes with
-the paper's 48-bit fixed-point operand split (Obs. 4); the `dtype`
-switch is the seam where that lands.  The keyswitch limb kernel is
-uint32 end to end and therefore exact on any hardware.
+Precision: the transform-domain planes default to f64, which interpret
+mode (CPU) executes natively and the 64-bit torus needs — an f32-only
+transform would put ~2^60+ of error into the accumulator, voiding
+decryption.  Mosaic has no f64, so `TaurusEngine` refuses this engine
+room on a TPU; the kernels themselves compile there at f32 (pinned by
+`tests/test_tpu_compile.py`), the starting point for the paper's
+split fixed-point planes (Obs. 4, ROADMAP S2).  The `dtype` switch is
+the seam where that lands.  The keyswitch limb kernel is uint32 end to
+end and therefore exact on any hardware.
 """
 from __future__ import annotations
 
@@ -46,15 +49,6 @@ from repro.kernels import external_product, fourstep_fft, keyswitch, ref
 U64 = jnp.uint64
 
 
-def bsk_to_planes(bsk_f: jax.Array, dtype=jnp.float64) -> jax.Array:
-    """Fourier BSK (n, k+1, level, k+1, M) complex -> kernel plane layout
-    (n, 2, J, K, M) with J = (k+1)*level rows matching the decomposition
-    order `external_product_mac` consumes."""
-    n, kp1, level, _, M = bsk_f.shape
-    flat = bsk_f.reshape(n, kp1 * level, kp1, M)
-    return jnp.stack([flat.real, flat.imag], axis=1).astype(dtype)
-
-
 def ksk_to_limbs(ksk: jax.Array) -> tuple[jax.Array, jax.Array]:
     """KSK (n_from, level, n_to+1) uint64 -> (hi, lo) (S, T) uint32 limb
     planes, S = n_from*level flattened in the digit order
@@ -67,7 +61,7 @@ def ksk_to_limbs(ksk: jax.Array) -> tuple[jax.Array, jax.Array]:
                    static_argnames=("params", "block_s", "interpret"))
 def keyswitch_fused(big_cts: jax.Array, ksk_hi: jax.Array, ksk_lo: jax.Array,
                     params: TFHEParams, *, block_s: int = 1024,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Batched big->small key switch through the limb MAC kernel.
 
     (B, big_n+1) -> (B, n+1); exact mod 2^64, bit-identical to
@@ -85,7 +79,7 @@ def keyswitch_fused(big_cts: jax.Array, ksk_hi: jax.Array, ksk_lo: jax.Array,
 def external_product_planes(bsk_i: jax.Array, glwe_cts: jax.Array,
                             params: TFHEParams, *, dtype=jnp.float64,
                             block_f: int = 2048,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool | None = None) -> jax.Array:
     """One resident BSK slice (2, J, K, M) applied to a GLWE batch
     (B, K, N) — decompose, forward FFT kernel, BRU MAC kernel, inverse
     FFT kernel, back onto the torus."""
@@ -111,7 +105,7 @@ def external_product_planes(bsk_i: jax.Array, glwe_cts: jax.Array,
 def blind_rotate_fused(lut_glwes: jax.Array, ms_cts: jax.Array,
                        bsk_planes: jax.Array, params: TFHEParams, *,
                        dtype=jnp.float64, block_f: int = 2048,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     """Batched blind rotation over the RESIDENT plane-layout BSK.
 
     lut_glwes (B, k+1, N); ms_cts (B, n+1) mod-switched to [0, 2N);
@@ -141,7 +135,8 @@ def pbs_batch_fused(big_cts: jax.Array, lut_polys: jax.Array,
                     bsk_planes: jax.Array, ksk_hi: jax.Array,
                     ksk_lo: jax.Array, params: TFHEParams, *,
                     dtype=jnp.float64, block_f: int = 2048,
-                    block_s: int = 1024, interpret: bool = True) -> jax.Array:
+                    block_s: int = 1024,
+                    interpret: bool | None = None) -> jax.Array:
     """The fused fast path for `TaurusEngine.lut_batch`:
     (B, k*N+1) + (B, N) LUT polys -> (B, k*N+1), all four PBS stages on
     the Pallas kernels with resident key operands."""
@@ -160,7 +155,7 @@ def pbs_batch_fused(big_cts: jax.Array, lut_polys: jax.Array,
 def pbs_small_fused(small_cts: jax.Array, lut_polys: jax.Array,
                     bsk_planes: jax.Array, params: TFHEParams, *,
                     dtype=jnp.float64, block_f: int = 2048,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """`pbs_batch_fused` minus the keyswitch: (B, n+1) small-key cts +
     (B, N) LUT polys -> (B, k*N+1).  `keyswitch_fused` followed by this
     function runs exactly the stages of `pbs_batch_fused`, so the
@@ -190,15 +185,16 @@ class FusedPbsPack:
     dtype: object = jnp.float64
     block_f: int = 2048
     block_s: int = 1024
-    interpret: bool = True
+    interpret: bool | None = None
 
     @classmethod
     def build(cls, bsk_f: jax.Array, ksk: jax.Array, params: TFHEParams, *,
               dtype=jnp.float64, block_f: int = 2048, block_s: int = 1024,
-              interpret: bool = True) -> "FusedPbsPack":
+              interpret: bool | None = None) -> "FusedPbsPack":
         dtype = jnp.dtype(dtype)
         hi, lo = ksk_to_limbs(ksk)
-        return cls(params, bsk_to_planes(bsk_f, dtype), hi, lo,
+        # bsk_f is already the kernels' (n, 2, J, K, M) plane layout
+        return cls(params, bsk_f.astype(dtype), hi, lo,
                    dtype=dtype, block_f=block_f, block_s=block_s,
                    interpret=interpret)
 

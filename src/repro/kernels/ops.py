@@ -1,7 +1,7 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-`interpret=True` everywhere in this container (CPU); on a real TPU the
-flag flips to False with identical call signatures.
+Interpret mode follows the platform (`repro.kernels.interpret_mode`):
+on for CPU, off on a TPU, with identical call signatures.
 
 `dtype` selects the transform-plane precision: f32 is the TPU-native
 mode, f64 is what the fused engine path (`repro.kernels.fused_pbs`)
@@ -15,24 +15,22 @@ import jax.numpy as jnp
 
 from repro.kernels import fourstep_fft, external_product, keyswitch, ref
 
-INTERPRET = True  # no TPU in this container; see DESIGN.md §5
-
 
 def negacyclic_fft(x: jax.Array, *, dtype=jnp.float32) -> jax.Array:
     """Forward negacyclic transform, (B, N) real -> (B, 2, N/2) planes."""
-    return fourstep_fft.fft_forward(x, interpret=INTERPRET, dtype=dtype)
+    return fourstep_fft.fft_forward(x, dtype=dtype)
 
 
 def negacyclic_ifft(spec: jax.Array, *, dtype=jnp.float32) -> jax.Array:
     """(B, 2, M) -> (B, 2M) plane-dtype coefficients."""
-    return fourstep_fft.fft_inverse(spec, interpret=INTERPRET, dtype=dtype)
+    return fourstep_fft.fft_inverse(spec, dtype=dtype)
 
 
 def bru_mac(dig: jax.Array, bsk: jax.Array, *, block_f: int = 2048,
             dtype=jnp.float32) -> jax.Array:
     """Blind-rotation MAC: (B,2,J,F) x (2,J,K,F) -> (B,2,K,F)."""
     return external_product.external_product_mac(
-        dig, bsk, block_f=block_f, interpret=INTERPRET, dtype=dtype
+        dig, bsk, block_f=block_f, dtype=dtype
     )
 
 
@@ -41,6 +39,6 @@ def lpu_keyswitch_mac(digits: jax.Array, ksk_u64: jax.Array,
     """digits (B,S) int32 x ksk (S,T) uint64 -> (B,T) uint64 (mod 2^64)."""
     hi, lo = ref.split_u64(ksk_u64)
     ohi, olo = keyswitch.keyswitch_mac(
-        digits, hi, lo, block_s=block_s, interpret=INTERPRET
+        digits, hi, lo, block_s=block_s
     )
     return ref.merge_u64(ohi, olo)

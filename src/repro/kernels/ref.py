@@ -15,22 +15,19 @@ U64 = jnp.uint64
 
 def fft_forward_ref(x: jax.Array) -> jax.Array:
     """real (B, N) -> (B, 2, N/2) f64 stacked re/im (same layout as kernel)."""
-    spec = core_fft.forward(x.astype(jnp.float64))
-    return jnp.stack([jnp.real(spec), jnp.imag(spec)], axis=1)
+    return core_fft.forward(x.astype(jnp.float64))
 
 
 def fft_inverse_ref(spec: jax.Array) -> jax.Array:
     """(B, 2, M) -> real (B, 2M) f64."""
-    z = spec[:, 0].astype(jnp.float64) + 1j * spec[:, 1].astype(jnp.float64)
-    return core_fft.inverse(z)
+    return core_fft.inverse(spec.astype(jnp.float64))
 
 
 def external_product_mac_ref(dig: jax.Array, bsk: jax.Array) -> jax.Array:
-    """dig (B,2,J,F), bsk (2,J,K,F) -> (B,2,K,F), f64 complex math."""
-    d = dig[:, 0].astype(jnp.float64) + 1j * dig[:, 1].astype(jnp.float64)
-    w = bsk[0].astype(jnp.float64) + 1j * bsk[1].astype(jnp.float64)
-    out = jnp.einsum("bjf,jkf->bkf", d, w)
-    return jnp.stack([jnp.real(out), jnp.imag(out)], axis=1)
+    """dig (B,2,J,F), bsk (2,J,K,F) -> (B,2,K,F), f64 plane math."""
+    out = core_fft.mac(jnp.swapaxes(dig, 1, 2).astype(jnp.float64),
+                       bsk.astype(jnp.float64))            # (B, K, 2, F)
+    return jnp.swapaxes(out, 1, 2)
 
 
 def keyswitch_mac_ref(digits: jax.Array, ksk: jax.Array) -> jax.Array:
@@ -39,7 +36,7 @@ def keyswitch_mac_ref(digits: jax.Array, ksk: jax.Array) -> jax.Array:
     Exact uint64 oracle for the limb kernel.
     """
     d = digits.astype(jnp.int64).astype(U64)     # two's complement mod 2^64
-    return jnp.einsum("bs,st->bt", d, ksk)       # wraparound dot
+    return jnp.sum(d[:, :, None] * ksk, axis=1, dtype=U64)     # wraps
 
 
 def split_u64(x: jax.Array):

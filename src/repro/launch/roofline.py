@@ -182,10 +182,10 @@ def pbs_round_model(params, batch: int) -> PbsRoundModel:
     """Build the per-round bandwidth model from TFHE parameters.
 
     Key bytes match `TaurusEngine.key_bytes` exactly: the Fourier BSK is
-    (n, k+1, level, k+1, N/2) complex128 and the KSK is
-    (big_n, ks_level, n+1) uint64 — the fused pack's plane/limb layouts
-    are byte-identical re-interpretations (2xf64 = c128, 2xu32 = u64),
-    so reference and pallas engines share one model.
+    (n, 2, (k+1)*level, k+1, N/2) float64 planes and the KSK is
+    (big_n, ks_level, n+1) uint64 — the fused pack's f64 planes and
+    (hi, lo) uint32 limbs hold the same bytes, so reference and pallas
+    engines share one model.
     """
     n, k, N = params.n, params.k, params.N
     bsk = n * (k + 1) * params.pbs_level * (k + 1) * (N // 2) * 16

@@ -154,6 +154,7 @@ class RequestHandle:
         self.result: Optional[dict] = None
         self.error: Optional[BaseException] = None
         self.retries = 0
+        self.shard: Optional[int] = None            # shard that ran it
         self.submitted_at: Optional[float] = None   # perf_counter stamps
         self.admitted_at: Optional[float] = None
         self.completed_at: Optional[float] = None
@@ -576,6 +577,7 @@ class ServeRuntime:
             tel.record("queue_wait", "serve", handle.submitted_at, wait_s,
                        request=req.request_id, client=req.client_id)
             self._h_queue_wait.observe(wait_s)
+        handle.shard = shard.index
         span = tel.span("request", cat="serve", request=req.request_id,
                         client=req.client_id, shard=shard.index)
         with span:
@@ -602,7 +604,9 @@ class ServeRuntime:
                     attempt["n"] += 1
                     if self.fault_hook is not None:
                         self.fault_hook(req, attempt["n"])
-                    return interp.run(req.graph, req.enc_inputs,
+                    return interp.run(req.graph,
+                                      list(shard.engine.place(
+                                          *req.enc_inputs)),
                                       on_node=on_node)
 
                 runner = StepRunner(step, self.fault, telemetry=tel)

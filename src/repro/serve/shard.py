@@ -16,7 +16,9 @@ N `EngineShard` workers.  Each shard owns
     controller (`repro.runtime.elastic`) resizing `max_inflight` from
     queue depth and recent fused-wave occupancy.
 
-Device routing (`repro.launch.mesh.shard_devices`): a multi-device
+Device routing (`repro.launch.mesh.shard_devices`): a one-device
+shard pins its engine to that device, so its keys, rounds and outputs
+live there and N shards on N chips run on N chips; a multi-device
 shard runs the reference backend over a 1-D data mesh; the pallas
 kernels run per-device, so a multi-device shard asking for pallas is
 the documented-unsupported `ConfigError` combination — `build_shards`
@@ -198,14 +200,15 @@ def build_shards(ctx, engine: Optional[TaurusEngine] = None, *,
         devs = tuple(device_sets[i])
         if i == 0 and engine is not None:
             eng = engine
-        else:
-            mesh = None
-            if len(devs) > 1 and kb == "reference":
-                mesh = shard_mesh(devs)
-            # len(devs) > 1 and pallas: the ConfigError combination —
-            # route around it with a single-device engine on devs[0]
-            eng = TaurusEngine.from_context(ctx, mesh=mesh,
+        elif len(devs) > 1 and kb == "reference":
+            eng = TaurusEngine.from_context(ctx, mesh=shard_mesh(devs),
                                             kernel_backend=kb)
+        else:
+            # one device, or the pallas + mesh ConfigError combination
+            # routed around: a single-device engine whose keys and
+            # rounds live on the shard's first device
+            eng = TaurusEngine.from_context(ctx, kernel_backend=kb,
+                                            device=devs[0])
         shards.append(EngineShard(
             i, ctx, eng, fused=fused, dedup=dedup, ks_dedup=ks_dedup,
             max_inflight=max_inflight,

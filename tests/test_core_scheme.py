@@ -113,7 +113,7 @@ def test_external_product_selects():
             p.pbs_base_log, p.pbs_level, p.glwe_std,
         )
         out = ggsw.external_product_fourier(
-            fft.forward(gg), ct, p.pbs_base_log, p.pbs_level
+            ggsw.ggsw_to_fourier(gg), ct, p.pbs_base_log, p.pbs_level
         )
         ph = glwe.decrypt_phase(gsk, out)
         got = torus.decode(ph, p.delta, p.plaintext_modulus)

@@ -113,8 +113,8 @@ def test_fft_f32_precision_supports_48bit_claim():
     """Observation 4: the paper's 48-bit fixed point <-> our split path.
     A single f32 four-step FFT roundtrip keeps relative error ~1e-6 of
     the spectrum scale; the scheme's noise budget at width<=10 needs
-    ~2^-40 of the torus, met by the f64 oracle used in the engine and by
-    the split-f32 TPU path (documented in DESIGN.md)."""
+    ~2^-40 of the torus, met by the f64 planes the engine runs; the
+    split-f32 TPU path is ROADMAP S2."""
     rng = np.random.default_rng(0)
     N = 4096
     x = rng.integers(-2 ** 30, 2 ** 30, (2, N)).astype(np.float64)

@@ -1,7 +1,7 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracles.
 
 Shape/dtype sweeps per the deliverable contract; tolerances account for
-the f32 kernel vs f64 oracle gap (documented in DESIGN.md §3).
+the f32 kernel vs f64 oracle gap.
 """
 import jax
 import jax.numpy as jnp
@@ -263,3 +263,21 @@ def test_engine_kernel_backend_validation(ctx_2bit):
     from repro.core.engine import TaurusEngine
     with pytest.raises(ValueError, match="kernel_backend"):
         TaurusEngine.from_context(ctx_2bit, kernel_backend="cuda")
+
+
+@pytest.mark.parametrize("platform,interpret", [("cpu", True),
+                                                ("tpu", False)])
+def test_interpret_mode_follows_platform(monkeypatch, platform, interpret):
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert interpret_mode() is interpret
+
+
+def test_pallas_engine_refused_on_tpu(ctx_2bit, monkeypatch):
+    """On a TPU the pallas room raises at construction, naming why;
+    the reference room still builds there."""
+    from repro.core.engine import ConfigError, TaurusEngine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ConfigError, match="Mosaic has no float64"):
+        TaurusEngine.from_context(ctx_2bit, kernel_backend="pallas")
+    assert TaurusEngine.from_context(ctx_2bit).kernel_backend == "reference"

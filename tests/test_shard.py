@@ -364,3 +364,70 @@ def test_elastic_cross_shard_fairness(ctx_2bit, engine_2bit):
     assert counters["serve.shard.1.admitted"] > 0
     for h in handles.values():
         assert int(ctx_2bit.decrypt(h.outputs()[0][0])) == 3
+
+
+PLACEMENT_SCRIPT = """
+import jax
+import numpy as np
+from repro.core.integer import IntegerContext
+from repro.core.params import TEST_PARAMS
+from repro.core.pbs import TFHEContext
+from repro.serve import ServeRuntime
+from repro.serve.programs import (decrypt_radix_output,
+                                  encrypt_request_inputs, radix_binop_program)
+
+devs = jax.devices()
+assert len(devs) == 4, devs
+ctx = TFHEContext.create(jax.random.key(60), TEST_PARAMS)
+ic = IntegerContext.create(ctx)
+m = ic.spec(8).msg_bits
+g = radix_binop_program("radix_add", 8, m)
+rng = np.random.default_rng(61)
+pairs = [tuple(int(v) for v in rng.integers(0, 256, 2)) for _ in range(8)]
+jobs = [encrypt_request_inputs(ic, jax.random.key(62 + i), list(p), 8)
+        for i, p in enumerate(pairs)]
+
+decrypts = {}
+for shards in (4, 1):
+    rt = ServeRuntime(ctx, None, shards=shards, max_inflight=2,
+                      start_paused=True)
+    handles = [rt.submit(g, enc, client_id=f"c{i}")
+               for i, enc in enumerate(jobs)]
+    rt.resume()
+    rt.drain()
+    outs = [h.outputs()[0] for h in handles]
+    decrypts[shards] = [decrypt_radix_output(ic, o, 8)[0] for o in outs]
+    if shards == 4:
+        for i, sh in enumerate(rt.shards):
+            assert sh.engine.mesh is None
+            assert sh.engine.bsk_f.devices() == {devs[i]}, i
+            assert sh.engine.ksk.devices() == {devs[i]}, i
+        for h, o in zip(handles, outs):
+            assert o.devices() == {devs[h.shard]}, (h.shard, o.devices())
+        assert {h.shard for h in handles} == {0, 1, 2, 3}
+    else:
+        assert rt.shards[0].engine.mesh is not None
+        assert set(rt.shards[0].engine.mesh.devices.flat) == set(devs)
+    rt.close()
+assert decrypts[4] == decrypts[1] == [(a + b) % 256 for a, b in pairs]
+print("PLACEMENT_OK")
+"""
+
+
+@pytest.mark.slow
+def test_four_shards_place_keys_rounds_and_outputs_on_own_devices():
+    """On four (virtual CPU) devices, `shards=4` pins every shard's BSK,
+    KSK and outputs to its own device, and decrypts exactly like
+    `shards=1`, the one shard spanning all four devices as a mesh."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    r = subprocess.run([sys.executable, "-c", PLACEMENT_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=900)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    assert "PLACEMENT_OK" in r.stdout

@@ -12,7 +12,9 @@ fragments reference the example's namespace and are covered by running
 
 All blocks within one file share a namespace: they are concatenated in
 order into one script and executed in a subprocess, so later snippets
-can build on earlier ones.  Only fences whose info string is exactly
+can build on earlier ones.  This parent never imports JAX: a chip
+belongs to one process, and each snippet's child may need it.  Only
+fences whose info string is exactly
 ``python`` run; ```text fences and annotated fences are
 documentation-only.
 """
