@@ -6,13 +6,15 @@ Two radix-add clients and one encrypted-GPT-2-block client (the
 quantize-to-radix lowering from `repro.fhe_ml`) run concurrently
 through `ServeRuntime` with a tracing `Telemetry` attached.  Every
 layer records spans: per-request `submit -> queue_wait -> admit ->
-pbs_round (fused batch id, dedup hits) -> completed`, the scheduler's
-leader-side `fused_round` dispatches, and the engine's `lut_batch`
-calls.  The script writes the trace, validates it (JSON shape, span
-nesting, per-request coverage), and prints the metrics snapshot
-headlines — open the file at https://ui.perfetto.dev or
-chrome://tracing to see the fleet's rounds barrier into shared
-batches.
+pbs_round (fused batch id, dedup hits) -> completed`, the host steps
+between rounds (`radix_linear`, `lut_encode`, `await_rows`,
+`row_keys`, `barrier_wait`), the scheduler's leader-side `fused_round`
+dispatches, every compile, and each engine-room execution's device
+time (`engine_room`, on a lane of its own).  The script writes the
+trace, validates it (JSON shape, span nesting, per-request coverage),
+and prints the metrics snapshot headlines — open the file at
+https://ui.perfetto.dev or chrome://tracing to see the fleet's rounds
+barrier into shared batches.
 
 The CI smoke lane runs this end-to-end and uploads the trace as a
 workflow artifact.
@@ -48,7 +50,6 @@ def main(argv=None) -> int:
     ctx = TFHEContext.create(jax.random.PRNGKey(0), params)
     engine = TaurusEngine.from_context(ctx)
     tel = Telemetry(trace=True)
-    engine.telemetry = tel          # engine-level lut_batch spans too
 
     client = Session(ctx, engine, backend="local")
     add_prog = client.trace(lambda a, b: a + b, IntSpec(BITS), IntSpec(BITS))
@@ -120,6 +121,10 @@ def main(argv=None) -> int:
     print(f"   BSK streamed {bw['bsk_bytes_streamed'] / 1e6:.1f} MB vs "
           f"{bw['bsk_bytes_unfused'] / 1e6:.1f} MB unfused "
           f"(saved {bw['bsk_bytes_saved'] / 1e6:.1f} MB)")
+    rooms = [e for e in events if e.name == "engine_room"]
+    print(f"   {len(rooms)} engine-room executions, "
+          f"{sum(e.dur for e in rooms):.1f}s busy; "
+          f"{snap['counters']['jit.compiles']} compiles")
     print(f"[trace_serve] {n_events} events -> {path} "
           f"(open in https://ui.perfetto.dev)")
     return 0

@@ -21,6 +21,14 @@ on first `lut_batch`, reused across every round — the paper's key-reuse
 strategy).  Both backends are decrypt-identical; the keyswitch stage is
 bit-identical.  The pallas room runs in interpret mode on CPU only and
 is refused on a TPU (see `ConfigError`).
+
+Timing: each engine-room execution is handed to
+`repro.obs.watch_execution` as it is enqueued.  Under a span of a
+tracing `Telemetry` (the scheduler's `fused_round`, a request's span)
+the telemetry's watcher records it as an `engine_room` span: its busy
+interval on the device, the program's name as the device trace prints
+it, its real and dispatched rows.  Otherwise that costs one thread-local
+lookup.
 """
 from __future__ import annotations
 
@@ -34,6 +42,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.core import batch as batch_mod, glwe, lwe, torus
 from repro.core.params import TFHEParams
+from repro.obs.trace import watch_execution
 
 U64 = jnp.uint64
 
@@ -88,10 +97,6 @@ class TaurusEngine:
     mesh: Optional[Mesh] = None
     data_axis: str = "data"
     batch_per_device: int = 12  # paper's round-robin depth (Fig. 13b)
-    # optional repro.obs.Telemetry; None keeps the hot path untouched.
-    # Set explicitly (engine.telemetry = tel) — the serve layer does NOT
-    # auto-attach, so a shared engine never pollutes baseline waves.
-    telemetry: Optional[object] = None
     # "reference" = jax PBS in repro.core.batch; "pallas" = fused kernel
     # path in repro.kernels.fused_pbs (interpret mode, CPU only).
     kernel_backend: str = "reference"
@@ -213,14 +218,18 @@ class TaurusEngine:
         return lwe.trivial(m, self.params.big_n)
 
     # -- PBS (BRU + LPU pipeline) -------------------------------------------
-    def lut_batch(self, cts: jax.Array, lut_polys: jax.Array) -> jax.Array:
+    def lut_batch(self, cts: jax.Array, lut_polys: jax.Array,
+                  rows: Optional[int] = None) -> jax.Array:
         """Apply per-ciphertext LUTs with noise refresh.
 
         cts: (B, k*N+1); lut_polys: (B, N) torus polys
         (`glwe.make_lut_poly` encodes integer tables).
-        Pads B up to a multiple of the cluster count.
+        Pads B up to a multiple of the cluster count.  `rows`: how many
+        of the B rows are real (the caller padded the rest to a compiled
+        width); it only labels the execution's `engine_room` span.
         """
         B = cts.shape[0]
+        rows = B if rows is None else rows
         if lut_polys.shape[0] != B:
             raise ValueError(
                 f"lut_batch: {B} ciphertexts but {lut_polys.shape[0]} LUT "
@@ -231,43 +240,35 @@ class TaurusEngine:
             cts = jnp.concatenate([cts, cts[:pad]], axis=0)
             lut_polys = jnp.concatenate([lut_polys, lut_polys[:pad]], axis=0)
         cts, lut_polys = self.place(cts, lut_polys)
-        tel = self.telemetry
-        span = (tel.span("lut_batch", cat="engine", rows=B, padded=pad)
-                if tel is not None else None)
-        if span is not None:
-            span.__enter__()
-        try:
-            if self.mesh is None:
-                if self.kernel_backend == "pallas":
-                    out = self.fused_pack.pbs_batch(cts, lut_polys)
-                else:
-                    out = batch_mod.pbs_batch(cts, lut_polys, self.bsk_f, self.ksk, self.params)
+        if self.mesh is None:
+            if self.kernel_backend == "pallas":
+                out = self.fused_pack.pbs_batch(cts, lut_polys)
+                program = "pbs_batch_fused"
             else:
-                # rows may arrive committed to another layout (a previous
-                # round's sharded output): reshard them onto the data axis
-                data_sh = NamedSharding(self.mesh, P(self.data_axis))
-                cts, lut_polys = jax.device_put((cts, lut_polys), data_sh)
-                out = self._mesh_pbs(cts, lut_polys, self.bsk_f, self.ksk,
-                                     self.params)
-                # hand the round back on one device: request threads
-                # then run single-device ops only, never concurrent
-                # multi-device programs whose collectives could pair up
-                # in different orders on different devices and deadlock
-                out = jax.device_put(
-                    out, SingleDeviceSharding(self.mesh.devices.flat[0]))
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
-        if tel is not None:
-            tel.counter(f"engine.lut_batches_{self.kernel_backend}").inc()
-            tel.counter("engine.lut_batches").inc()
-            tel.counter("engine.pbs_rows").inc(B + pad)
-            tel.counter("engine.pbs_rows_padded").inc(pad)
-            tel.histogram("engine.lut_batch_rows").observe(B)
+                out = batch_mod.pbs_batch(cts, lut_polys, self.bsk_f,
+                                          self.ksk, self.params)
+                program = "pbs_batch"
+        else:
+            # rows may arrive committed to another layout (a previous
+            # round's sharded output): reshard them onto the data axis
+            data_sh = NamedSharding(self.mesh, P(self.data_axis))
+            cts, lut_polys = jax.device_put((cts, lut_polys), data_sh)
+            out = self._mesh_pbs(cts, lut_polys, self.bsk_f, self.ksk,
+                                 self.params)
+            program = "pbs_batch"
+        watch_execution(out, program=program, rows=rows, padded=B + pad)
+        if self.mesh is not None:
+            # hand the round back on one device: request threads
+            # then run single-device ops only, never concurrent
+            # multi-device programs whose collectives could pair up
+            # in different orders on different devices and deadlock
+            out = jax.device_put(
+                out, SingleDeviceSharding(self.mesh.devices.flat[0]))
         return out[:B]
 
     # -- the split PBS entries (KS-level partial dedup, ISSUE 10) -----------
-    def keyswitch(self, big_cts: jax.Array) -> jax.Array:
+    def keyswitch(self, big_cts: jax.Array,
+                  rows: Optional[int] = None) -> jax.Array:
         """The keyswitch stage alone: (B, k*N+1) big-key cts ->
         (B, n+1) small-key cts.  Bit-identical to the first stage of
         `lut_batch` on both backends (the pallas limb kernel is exact
@@ -279,13 +280,21 @@ class TaurusEngine:
                 "keyswitch/lut_batch_small need a single-device engine "
                 "(supports_ks_split) — the mesh path dispatches full PBS "
                 "rounds only")
+        B = big_cts.shape[0]
+        rows = B if rows is None else rows
         (big_cts,) = self.place(big_cts)
         if self.kernel_backend == "pallas":
-            return self.fused_pack.keyswitch(big_cts)
-        return batch_mod.keyswitch_batch_jit(big_cts, self.ksk, self.params)
+            out = self.fused_pack.keyswitch(big_cts)
+            program = "keyswitch_fused"
+        else:
+            out = batch_mod.keyswitch_batch_jit(big_cts, self.ksk,
+                                                self.params)
+            program = "keyswitch_batch_jit"
+        watch_execution(out, program=program, rows=rows, padded=B)
+        return out
 
-    def lut_batch_small(self, small_cts: jax.Array,
-                        lut_polys: jax.Array) -> jax.Array:
+    def lut_batch_small(self, small_cts: jax.Array, lut_polys: jax.Array,
+                        rows: Optional[int] = None) -> jax.Array:
         """`lut_batch` minus the keyswitch: (B, n+1) small-key cts +
         (B, N) LUT polys -> (B, k*N+1) refreshed big-key cts.
         `keyswitch` then `lut_batch_small` computes exactly what
@@ -296,31 +305,21 @@ class TaurusEngine:
                 "(supports_ks_split) — the mesh path dispatches full PBS "
                 "rounds only")
         B = small_cts.shape[0]
+        rows = B if rows is None else rows
         if lut_polys.shape[0] != B:
             raise ValueError(
                 f"lut_batch_small: {B} ciphertexts but "
                 f"{lut_polys.shape[0]} LUT polynomials — counts must "
                 f"match per batch row")
         small_cts, lut_polys = self.place(small_cts, lut_polys)
-        tel = self.telemetry
-        span = (tel.span("lut_batch_small", cat="engine", rows=B)
-                if tel is not None else None)
-        if span is not None:
-            span.__enter__()
-        try:
-            if self.kernel_backend == "pallas":
-                out = self.fused_pack.pbs_from_small(small_cts, lut_polys)
-            else:
-                out = batch_mod.pbs_batch_small(small_cts, lut_polys,
-                                                self.bsk_f, self.params)
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
-        if tel is not None:
-            tel.counter(f"engine.lut_batches_{self.kernel_backend}").inc()
-            tel.counter("engine.lut_batches").inc()
-            tel.counter("engine.pbs_rows").inc(B)
-            tel.histogram("engine.lut_batch_rows").observe(B)
+        if self.kernel_backend == "pallas":
+            out = self.fused_pack.pbs_from_small(small_cts, lut_polys)
+            program = "pbs_small_fused"
+        else:
+            out = batch_mod.pbs_batch_small(small_cts, lut_polys,
+                                            self.bsk_f, self.params)
+            program = "pbs_batch_small"
+        watch_execution(out, program=program, rows=rows, padded=B)
         return out
 
     def lut_batch_tables(self, cts: jax.Array, tables) -> jax.Array:

@@ -26,9 +26,16 @@ Carry propagation strategies:
              base-2 path drops its D-round ripple for
              2*ceil(log2(D)) + 2 rounds.
 All run every round as a single `lut_batch` call of >= D ciphertexts.
+
+Tracing: with a tracing telemetry, host work run under
+`IntegerContext.linear_stretch` (the serving interpreter runs every
+request's node loop so) is timed as `radix_linear` spans, one per
+stretch between two rounds (each round cuts the stretch), and
+LUT-polynomial encoding as `lut_encode`.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import threading
@@ -41,6 +48,7 @@ from repro.core import glwe, lwe, torus
 from repro.core.engine import TaurusEngine
 from repro.core.params import TFHEParams
 from repro.core.pbs import TFHEContext
+from repro.obs import NOOP_RECORDER
 
 U64 = jnp.uint64
 
@@ -250,6 +258,14 @@ def _pad_batch(b: int) -> int:
     return -(-b // 32) * 32
 
 
+class _Stretch(threading.local):
+    """Per thread: how deep in linear host work it is, and the open
+    `radix_linear` span (the serving fan-out runs several vector
+    threads through ONE context)."""
+    depth = 0
+    span = None
+
+
 # ---------------------------------------------------------------------------
 # client + server API
 # ---------------------------------------------------------------------------
@@ -261,8 +277,8 @@ class IntegerContext:
     ctx: TFHEContext
     engine: TaurusEngine
     pad_batches: bool = True
-    # optional repro.obs.Telemetry; every nonlinear round publishes
-    # integer.* series into its registry when set
+    # optional repro.obs.Telemetry: every nonlinear round counts its
+    # logical rows as integer.pbs; tracing times the host steps
     telemetry: object = None
     stats: dict = dataclasses.field(default_factory=lambda: {
         "pbs": 0, "lut_batches": 0, "batch_sizes": [], "dispatch_sizes": []})
@@ -271,6 +287,8 @@ class IntegerContext:
     # several vector threads through ONE context, so guard them
     _stats_lock: object = dataclasses.field(
         default_factory=threading.Lock, repr=False)
+    _stretch: _Stretch = dataclasses.field(default_factory=_Stretch,
+                                           repr=False)
 
     @classmethod
     def create(cls, ctx: TFHEContext, engine: TaurusEngine | None = None,
@@ -311,6 +329,50 @@ class IntegerContext:
         expect = jnp.asarray(rct.spec.to_digits(value))
         return np.asarray(jax.vmap(self.ctx.decrypt_noise)(rct.digits, expect))
 
+    # -- tracing: host work between two rounds ------------------------------
+    @contextlib.contextmanager
+    def linear_stretch(self):
+        """With tracing on, time the body's host work as `radix_linear`
+        spans, one per stretch between two rounds (`cut_stretch` ends a
+        stretch).  A nested body joins the stretch around it."""
+        tel, st = self.telemetry, self._stretch
+        if tel is None or not tel.tracing:
+            yield
+            return
+        st.depth += 1
+        if st.depth == 1:
+            self._open_stretch()
+        try:
+            yield
+        finally:
+            st.depth -= 1
+            if st.depth == 0:
+                self._close_stretch()
+
+    @contextlib.contextmanager
+    def cut_stretch(self):
+        """The body is no linear work (a round, or threads running
+        rounds): the open stretch ends before it, a new one after it."""
+        st = self._stretch
+        cut = st.span is not None
+        if cut:
+            self._close_stretch()
+        try:
+            yield
+        finally:
+            if cut:
+                self._open_stretch()
+
+    def _open_stretch(self) -> None:
+        st = self._stretch
+        st.span = self.telemetry.span("radix_linear", cat="radix")
+        st.span.__enter__()
+
+    def _close_stretch(self) -> None:
+        st = self._stretch
+        st.span.__exit__(None, None, None)
+        st.span = None
+
     # -- the one nonlinear primitive ----------------------------------------
     def _lut(self, cts: jax.Array, tables: np.ndarray) -> jax.Array:
         """One PBS batch: per-ciphertext integer tables -> refreshed cts.
@@ -327,18 +389,16 @@ class IntegerContext:
                 reps = -(-p // b)
                 dispatch = jnp.tile(cts, (reps, 1))[:p]
                 dtables = np.tile(tables, (reps, 1))[:p]
-        out = self.engine.lut_batch(dispatch, self._polys(dtables))
+        polys = self._polys(dtables)
+        with self.cut_stretch():
+            out = self.engine.lut_batch(dispatch, polys)
         with self._stats_lock:
             self.stats["lut_batches"] += 1
             self.stats["pbs"] += b
             self.stats["batch_sizes"].append(b)
             self.stats["dispatch_sizes"].append(int(dispatch.shape[0]))
-        tel = self.telemetry
-        if tel is not None:
-            tel.counter("integer.lut_batches").inc()
-            tel.counter("integer.pbs").inc(b)
-            tel.counter("integer.pbs_dispatched").inc(int(dispatch.shape[0]))
-            tel.histogram("integer.batch_rows").observe(b)
+        if self.telemetry is not None:
+            self.telemetry.counter("integer.pbs").inc(b)
         return out[:b]
 
     def _polys(self, tables: np.ndarray) -> jax.Array:
@@ -347,8 +407,11 @@ class IntegerContext:
         # serving contexts share the row encodes
         key = tables.tobytes()
         if key not in self._poly_cache:
-            self._poly_cache[key] = glwe.make_lut_polys_cached(
-                tables, self.params)
+            rec = (self.telemetry.recorder if self.telemetry is not None
+                   else NOOP_RECORDER)
+            with rec.span("lut_encode", cat="radix"):
+                self._poly_cache[key] = glwe.make_lut_polys_cached(
+                    tables, self.params)
         return self._poly_cache[key]
 
     def _trivial_digits(self, spec: RadixSpec, value: int) -> jax.Array:

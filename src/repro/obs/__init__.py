@@ -6,14 +6,19 @@ One `Telemetry` object threads through every layer of the serve path:
   metrics    typed counters/gauges/latency histograms in a
              `MetricsRegistry` (p50/p95/p99 from streaming quantile
              sketches), published by `ServeRuntime`,
-             `FusedLutScheduler`, `IrInterpreter`, `IntegerContext`,
-             and `TaurusEngine.lut_batch`; read through one
-             `snapshot()` (also `ServeRuntime.metrics()`).
+             `FusedLutScheduler`, `IntegerContext` and JAX's own
+             compile events (`jit.*`); read through one `snapshot()`
+             (also `ServeRuntime.metrics()`).
   tracing    request spans — submit -> queue-wait -> admit -> per-PBS-
              round (fused batch id, occupancy, dedup hits) ->
-             complete/retry/fail — via a lock-cheap per-thread
-             `TraceRecorder`, exportable as Chrome-trace JSON
-             (Perfetto / chrome://tracing) or inspected in-memory.
+             complete/retry/fail — the host steps between rounds, each
+             engine-room execution's device time (`engine_room`) and
+             every compile, via a lock-cheap per-thread
+             `TraceRecorder`; each span has an id and a parent, and
+             also enters a `jax.profiler.TraceAnnotation`, so a JAX
+             profile shows it beside the device's ops.  Exportable as
+             Chrome-trace JSON (Perfetto / chrome://tracing) or
+             inspected in-memory.
   bandwidth  a `BandwidthLedger` accounting BSK/KSK bytes streamed per
              fused round vs. the unfused counterfactual — the paper's
              key-reuse saving as a measured quantity
@@ -39,13 +44,62 @@ mixed radix + GPT-2-block serving run.
 """
 from __future__ import annotations
 
+import threading
+import time
+import weakref
+
 from repro.obs.bandwidth import (NULL_LEDGER, BandwidthLedger, NullLedger,
                                  engine_key_bytes)
 from repro.obs.metrics import (NULL_REGISTRY, Counter, Gauge, Histogram,
                                MetricsRegistry, NullRegistry, Snapshot,
                                StatsView)
 from repro.obs.trace import (NOOP_RECORDER, NoopRecorder, SpanEvent,
-                             TraceRecorder, validate_chrome_trace)
+                             TraceRecorder, adopt, current_span,
+                             validate_chrome_trace, watch_execution)
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "jit.cache_hits",
+                 "/jax/compilation_cache/cache_misses": "jit.cache_misses"}
+
+# every live Telemetry hears JAX's compile events through ONE listener
+# pair, registered with jax.monitoring on the first Telemetry
+_live: "weakref.WeakSet" = weakref.WeakSet()
+_live_lock = threading.Lock()
+_listening = False
+
+
+def _listen(tel: "Telemetry") -> None:
+    global _listening
+    with _live_lock:
+        _live.add(tel)
+        if not _listening:
+            import jax.monitoring as mon
+            mon.register_event_duration_secs_listener(_on_duration)
+            mon.register_event_listener(_on_event)
+            _listening = True
+
+
+def _live_telemetry() -> list:
+    with _live_lock:
+        return list(_live)
+
+
+def _on_duration(name: str, secs: float, **_) -> None:
+    """A backend compile: counted, and a `compile` span [now - secs,
+    now] on the compiling thread."""
+    if name != _COMPILE_EVENT:
+        return
+    now = time.perf_counter()
+    for tel in _live_telemetry():
+        tel.counter("jit.compiles").inc()
+        tel.record("compile", "jax", now - secs, secs)
+
+
+def _on_event(name: str, **_) -> None:
+    counter = _CACHE_EVENTS.get(name)
+    if counter is not None:
+        for tel in _live_telemetry():
+            tel.counter(counter).inc()
 
 
 class Telemetry:
@@ -59,6 +113,12 @@ class Telemetry:
         self.registry = MetricsRegistry() if metrics else NULL_REGISTRY
         self.recorder = TraceRecorder() if trace else NOOP_RECORDER
         self.bandwidth = BandwidthLedger() if metrics else NULL_LEDGER
+        if trace:
+            # a traced window reads its jit.* counters as 0, not absent,
+            # when nothing compiled in it
+            for name in ("jit.compiles", *_CACHE_EVENTS.values()):
+                self.counter(name)
+        _listen(self)
 
     @classmethod
     def disabled(cls) -> "Telemetry":
@@ -112,6 +172,6 @@ __all__ = [
     "BandwidthLedger", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "NOOP_RECORDER", "NULL_LEDGER", "NULL_REGISTRY", "NoopRecorder",
     "NullLedger", "NullRegistry", "Snapshot", "SpanEvent", "StatsView",
-    "Telemetry",
-    "TraceRecorder", "engine_key_bytes", "validate_chrome_trace",
+    "Telemetry", "TraceRecorder", "adopt", "current_span",
+    "engine_key_bytes", "validate_chrome_trace", "watch_execution",
 ]

@@ -2,9 +2,9 @@
 `MetricsRegistry` with a single `snapshot()` contract.
 
 Every layer of the serve path publishes here — `ServeRuntime` request
-outcomes, `FusedLutScheduler` round composition, `IntegerContext` /
-`TaurusEngine.lut_batch` PBS accounting — so one snapshot shows the
-whole stack.  Instruments are cheap (one small lock each, no
+outcomes, `FusedLutScheduler` round composition, `IntegerContext` PBS
+accounting, JAX's compile events — so one snapshot shows the whole
+stack.  Instruments are cheap (one small lock each, no
 allocation on the hot path) and process-local; nothing is exported
 anywhere unless a caller reads `snapshot()`.
 

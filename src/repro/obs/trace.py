@@ -10,6 +10,25 @@ records point events (submit/complete/retry markers) and `record()`
 backfills an interval measured elsewhere (e.g. a request's queue wait,
 whose endpoints were stamped by other threads).
 
+Identity and cause: every event gets a process-unique `id` and a
+`parent`, the innermost span of the same recorder open on its thread
+when it was recorded.  A span opened under one that carries
+`request=<id>` inherits that arg, so every span of a request's worker
+thread names its request; `adopt(current_span())` carries both onto a
+helper thread (the interpreter's radix fan-out).  Spans of different
+threads are linked by args such as the fused `round` id.
+
+One clock with the device: while a recorder is on, each `span()` also
+enters a `jax.profiler.TraceAnnotation` of the same name, so in any JAX
+profile (XProf, Perfetto) the program's spans sit on the host plane on
+the profiler's clock, beside the device's ops.  Backfilled `record()`
+spans are recorder-only.
+
+The engine room: `watch()` hands an execution's output to an
+`ExecutionWatcher` (`repro.obs.watcher`), which records the execution's
+busy interval on the device as an `engine_room` span on a lane of its
+own; nothing on the serving path waits for it.
+
 Two export forms:
 
   * `events()` / `spans()` — the structured in-memory form tests
@@ -17,8 +36,8 @@ Two export forms:
   * `chrome_trace()` / `write(path)` — Chrome trace-event JSON
     (`{"traceEvents": [...]}`), loadable in Perfetto
     (https://ui.perfetto.dev) or chrome://tracing.  Complete events
-    ("ph": "X") carry microsecond ts/dur; per-thread metadata events
-    name the lanes.
+    ("ph": "X") carry microsecond ts/dur and the event's `id` and
+    `parent` in their args; per-thread metadata events name the lanes.
 
 `validate_chrome_trace` checks an exported file the way the CI smoke
 lane does: valid JSON, required keys per event, and — per thread lane
@@ -31,11 +50,17 @@ path pays one method call and a kwargs dict when tracing is off.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
 import json
 import threading
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+from repro.obs.watcher import ExecutionWatcher
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,12 +73,61 @@ class SpanEvent:
     tid: int                  # small per-recorder thread lane id
     thread: str               # thread name at first record
     args: dict
+    id: int = 0               # process-unique event id
+    parent: Optional[int] = None   # id of the span open around it
+
+
+_ids = itertools.count(1)      # next() is atomic under the GIL
+_local = threading.local()     # .stack: the spans open on this thread
+
+
+def _stack() -> list:
+    st = getattr(_local, "stack", None)
+    if st is None:
+        st = _local.stack = []
+    return st
+
+
+def current_span() -> Optional["_SpanCtx"]:
+    """The innermost span of a tracing recorder open on the calling
+    thread (or adopted by it), else None."""
+    st = getattr(_local, "stack", None)
+    return st[-1] if st else None
+
+
+@contextlib.contextmanager
+def adopt(cause: Optional["_SpanCtx"]):
+    """Run the body as if `cause` (another thread's `current_span()`)
+    were open on this thread: spans opened here take it as parent and
+    inherit its `request`.  No-op for None (tracing off)."""
+    if cause is None:
+        yield
+        return
+    st = _stack()
+    st.append(cause)
+    try:
+        yield
+    finally:
+        st.remove(cause)
+
+
+def watch_execution(out, **args) -> None:
+    """Time one engine-room execution whose output `out` was just
+    enqueued: when a span of a tracing recorder is open on the calling
+    thread, that recorder's watcher records the execution as an
+    `engine_room` span with `args` and the open span's `round`, child of
+    that span.  Otherwise (tracing off) it does nothing and holds
+    nothing."""
+    sp = current_span()
+    if sp is not None:
+        sp._rec.watch(out, sp, round=sp.args.get("round"), **args)
 
 
 class _SpanCtx:
     """Context manager recording one span on the current thread."""
 
-    __slots__ = ("_rec", "name", "cat", "args", "_t0")
+    __slots__ = ("_rec", "name", "cat", "args", "_t0", "id", "parent",
+                 "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, cat: str, args: dict):
         self._rec = rec
@@ -67,13 +141,24 @@ class _SpanCtx:
         self.args.update(kw)
 
     def __enter__(self) -> "_SpanCtx":
+        self.id = next(_ids)
+        self.parent = self._rec._cause(self.args)
+        _stack().append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        st = _stack()
+        if st and st[-1] is self:
+            st.pop()
+        else:
+            st.remove(self)
         self._rec._append(self.name, self.cat, self._t0, t1 - self._t0,
-                          self.args)
+                          self.args, self.parent, self.id)
 
 
 class _NoopSpan:
@@ -132,6 +217,7 @@ class TraceRecorder:
         self._lock = threading.Lock()
         self._buffers: list = []          # [(tid, thread_name, list)]
         self._tls = threading.local()
+        self._watcher = None              # ExecutionWatcher, on first watch
 
     # -- recording -----------------------------------------------------------
     def _buf(self) -> list:
@@ -147,9 +233,26 @@ class TraceRecorder:
         return buf
 
     def _append(self, name: str, cat: str, ts: float, dur: Optional[float],
-                args: dict) -> None:
+                args: dict, parent: Optional[int],
+                eid: Optional[int] = None) -> None:
         # list.append on a thread-owned list: no lock on the hot path
-        self._buf().append((name, cat, ts, dur, args))
+        self._buf().append((name, cat, ts, dur, args,
+                            eid if eid is not None else next(_ids), parent))
+
+    def _cause(self, args: dict) -> Optional[int]:
+        """The id of this recorder's innermost span open on the calling
+        thread; copies its `request` into `args` unless set there."""
+        for sp in reversed(getattr(_local, "stack", ())):
+            if sp._rec is self:
+                req = sp.args.get("request")
+                if req is not None:
+                    args.setdefault("request", req)
+                return sp.id
+        return None
+
+    def _append_here(self, name: str, cat: str, ts: float,
+                     dur: Optional[float], args: dict) -> None:
+        self._append(name, cat, ts, dur, args, self._cause(args))
 
     def span(self, name: str, cat: str = "serve", **args) -> _SpanCtx:
         """Open a span on the current thread::
@@ -161,25 +264,39 @@ class TraceRecorder:
         return _SpanCtx(self, name, cat, args)
 
     def instant(self, name: str, cat: str = "serve", **args) -> None:
-        self._append(name, cat, time.perf_counter(), None, args)
+        self._append_here(name, cat, time.perf_counter(), None, args)
 
     def record(self, name: str, cat: str, ts: float, dur: float,
                **args) -> None:
         """Backfill an interval whose endpoints were measured elsewhere
         (perf_counter timebase); lands on the calling thread's lane."""
-        self._append(name, cat, ts, dur, args)
+        self._append_here(name, cat, ts, dur, args)
+
+    def watch(self, out, parent: Optional[_SpanCtx], **args) -> None:
+        """Time the device execution whose output is `out` (just
+        enqueued): the watcher records it as an `engine_room` span with
+        `args`, child of `parent`.  Returns at once."""
+        if self._watcher is None:
+            with self._lock:
+                if self._watcher is None:
+                    self._watcher = ExecutionWatcher(self)
+        self._watcher.watch(out, time.perf_counter(), args,
+                            parent.id if parent is not None else None)
 
     # -- structured export (the in-memory form tests assert against) --------
     def events(self) -> list:
-        """Every recorded event as `SpanEvent`s, sorted by start time."""
+        """Every recorded event as `SpanEvent`s, sorted by start time,
+        once the watcher has recorded every execution handed to it."""
+        if self._watcher is not None:
+            self._watcher.flush()
         with self._lock:
             snap = [(tid, tname, list(buf))
                     for tid, tname, buf in self._buffers]
         out = []
         for tid, tname, buf in snap:
-            for name, cat, ts, dur, args in buf:
+            for name, cat, ts, dur, args, eid, parent in buf:
                 out.append(SpanEvent(name, cat, ts, dur, tid, tname,
-                                     dict(args)))
+                                     dict(args), eid, parent))
         out.sort(key=lambda e: e.ts)
         return out
 
@@ -203,7 +320,7 @@ class TraceRecorder:
             ev = {
                 "name": e.name, "cat": e.cat, "pid": 1, "tid": e.tid,
                 "ts": (e.ts - self._t0) * 1e6,
-                "args": e.args,
+                "args": {**e.args, "id": e.id, "parent": e.parent},
             }
             if e.dur is None:
                 ev["ph"] = "i"

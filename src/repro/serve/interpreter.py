@@ -24,6 +24,12 @@ A radix node's tensor has its digit vector on the LAST axis; each
 vector executes through `IntegerContext`
 (`repro.api.backends.eval_radix_vector`, shared with the eager backend
 so the radix semantics has one definition).
+
+Tracing: the interpreter's host work between two rounds (linear nodes,
+node bookkeeping, the radix layer's digit arithmetic) is one
+`radix_linear` span per stretch, LUT encoding is `lut_encode`, and the
+fan-out threads adopt the calling thread's open span, so their spans
+name the request too.
 """
 from __future__ import annotations
 
@@ -39,6 +45,7 @@ from repro.compiler.ir import Graph, RADIX_OPS
 from repro.core import glwe
 from repro.core.engine import TaurusEngine
 from repro.core.integer import IntegerContext
+from repro.obs import NOOP_RECORDER, adopt, current_span
 
 
 class IrInterpreter:
@@ -76,6 +83,8 @@ class IrInterpreter:
         if pad_rounds is None:
             pad_rounds = not getattr(self.engine, "fused", False)
         self.telemetry = telemetry
+        self._rec = (telemetry.recorder if telemetry is not None
+                     else NOOP_RECORDER)
         self.int_ctx = IntegerContext(ctx, self.engine,
                                       pad_batches=pad_rounds,
                                       telemetry=telemetry)
@@ -87,8 +96,9 @@ class IrInterpreter:
     def _lut_poly(self, table: np.ndarray) -> jax.Array:
         key = np.ascontiguousarray(table).tobytes()
         if key not in self._poly_cache:
-            self._poly_cache[key] = glwe.make_lut_polys_cached(
-                np.asarray(table)[None], self.params)[0]
+            with self._rec.span("lut_encode", cat="radix"):
+                self._poly_cache[key] = glwe.make_lut_polys_cached(
+                    np.asarray(table)[None], self.params)[0]
         return self._poly_cache[key]
 
     # upper bound on fan-out threads per radix node: beyond this, each
@@ -107,13 +117,15 @@ class IrInterpreter:
         errors: list = []
         nt = min(V, self.MAX_FANOUT)
         slices = [range(w, V, nt) for w in range(nt)]
+        cause = current_span()
 
         def work(idx) -> None:
             try:
-                for v in idx:
-                    outs[v] = eval_radix_vector(
-                        self.int_ctx, n.op, spec, a[v],
-                        None if b is None else b[v], max_val=max_val)
+                with adopt(cause), self.int_ctx.linear_stretch():
+                    for v in idx:
+                        outs[v] = eval_radix_vector(
+                            self.int_ctx, n.op, spec, a[v],
+                            None if b is None else b[v], max_val=max_val)
             except BaseException as err:  # noqa: BLE001 — re-raised below
                 errors.append(err)
             finally:
@@ -169,7 +181,8 @@ class IrInterpreter:
             b = vals[n.inputs[1]].reshape(-1, d, width)
         sched = getattr(self.engine, "_scheduler", None)
         if self.intra_fuse and sched is not None and a.shape[0] > 1:
-            outs = self._radix_fanout(n, spec, a, b, sched, max_val=mv)
+            with ic.cut_stretch():
+                outs = self._radix_fanout(n, spec, a, b, sched, max_val=mv)
         else:
             outs = [eval_radix_vector(ic, n.op, spec, a[v],
                                       None if b is None else b[v],
@@ -189,25 +202,28 @@ class IrInterpreter:
         readable while later nodes still execute."""
         vals: dict = {}
         it = iter(enc_inputs)
-        for n in g.nodes:
-            if n.op == "input":
-                vals[n.id] = next(it)
-            else:
-                out = eval_linear_ct_op(n, vals, self.params)
-                if out is not None:
-                    vals[n.id] = out
-                elif n.op == "lut":
-                    cts = vals[n.inputs[0]]
-                    poly = self._lut_poly(n.attrs["table"])
-                    polys = jnp.broadcast_to(poly,
-                                             (cts.shape[0],) + poly.shape)
-                    vals[n.id] = self.engine.lut_batch(cts, polys)
-                elif n.op in RADIX_OPS:
-                    vals[n.id] = self._radix(n, vals)
+        ic = self.int_ctx
+        with ic.linear_stretch():
+            for n in g.nodes:
+                if n.op == "input":
+                    vals[n.id] = next(it)
                 else:
-                    raise ValueError(n.op)
-            if on_node is not None:
-                on_node(n.id, vals[n.id])
+                    out = eval_linear_ct_op(n, vals, self.params)
+                    if out is not None:
+                        vals[n.id] = out
+                    elif n.op == "lut":
+                        cts = vals[n.inputs[0]]
+                        poly = self._lut_poly(n.attrs["table"])
+                        polys = jnp.broadcast_to(
+                            poly, (cts.shape[0],) + poly.shape)
+                        with ic.cut_stretch():
+                            vals[n.id] = self.engine.lut_batch(cts, polys)
+                    elif n.op in RADIX_OPS:
+                        vals[n.id] = self._radix(n, vals)
+                    else:
+                        raise ValueError(n.op)
+                if on_node is not None:
+                    on_node(n.id, vals[n.id])
         return vals
 
     def run_outputs(self, g: Graph, enc_inputs: list) -> list:

@@ -88,10 +88,17 @@ class FusedEngineProxy:
                 f"lut_batch: {cts.shape[0]} ciphertexts but "
                 f"{lut_polys.shape[0]} LUT polynomials")
         sched = self._scheduler
-        # pre-hash for full-row dedup AND the KS-level partial dedup —
-        # both consume these digests on the leader's dict-scan path
-        keys = (_row_keys(cts, lut_polys)
-                if (sched.dedup or sched.ks_dedup) else None)
+        keys = None
+        if sched.dedup or sched.ks_dedup:
+            # pre-hash for full-row dedup AND the KS-level partial dedup —
+            # both consume these digests on the leader's dict-scan path.
+            # The wait for the round's inputs and the host copy are timed
+            # apart, so a gap lands on the copy, not on the device
+            tel = sched.telemetry
+            with tel.span("await_rows", cat="sched"):
+                jax.block_until_ready((cts, lut_polys))
+            with tel.span("row_keys", cat="sched"):
+                keys = _row_keys(cts, lut_polys)
         return sched.submit(self._engine, cts, lut_polys, keys)
 
     def lut_batch_tables(self, cts: jax.Array, tables) -> jax.Array:
@@ -247,7 +254,8 @@ class FusedLutScheduler:
                     # leaders/unregister notify promptly; the timeout only
                     # bounds how late a deadline-triggered partial dispatch
                     # can fire
-                    self._cv.wait(timeout=0.25)
+                    with self.telemetry.span("barrier_wait", cat="sched"):
+                        self._cv.wait(timeout=0.25)
             # the fused batch id this round landed in (the leader stamps it)
             sp.set(round=entry.round_id)
         if entry.error is not None:
@@ -357,7 +365,9 @@ class FusedLutScheduler:
                     if pu > u:
                         reps = -(-pu // u)
                         ucts = jnp.tile(ucts, (reps, 1))[:pu]
-                body = engine.keyswitch(ucts)[:u][ct_inv]
+                # an engine call's last argument, the real row count,
+                # labels its engine_room span
+                body = engine.keyswitch(ucts, u)[:u][ct_inv]
             else:
                 body = cts
             if self.pad_batches:
@@ -370,9 +380,9 @@ class FusedLutScheduler:
             sp.set(dedup_hits=hits, ks_dedup_hits=ks_hits,
                    dispatched=nb, padded=padded)
             if ks_plan is not None:
-                out = engine.lut_batch_small(body, polys)[:nb]
+                out = engine.lut_batch_small(body, polys, nb)[:nb]
             else:
-                out = engine.lut_batch(body, polys)[:nb]
+                out = engine.lut_batch(body, polys, nb)[:nb]
         self._inc("fused_rounds")
         self._inc("logical_luts", n)
         self._inc("dedup_hits", hits)

@@ -523,3 +523,227 @@ def test_snapshot_diff_bandwidth_and_telemetry_roundtrip():
     assert delta["bandwidth"]["bsk_bytes_streamed"] == 1000
     assert delta["bandwidth"]["bsk_bytes_unfused"] == 3000
     json.dumps(delta)
+
+
+# --- the round's timeline: ids, causes, engine room, profiler, compiles ------
+
+def _lanes(events):
+    by: dict = {}
+    for e in events:
+        if e.dur is not None:
+            by.setdefault(e.tid, []).append(e)
+    return by
+
+
+@pytest.fixture(scope="module")
+def traced_fanout(ctx_4bit):
+    """ONE traced request whose radix add fans out over two digit
+    vectors, on a private engine whose three engine-room entries count
+    their calls."""
+    import jax.numpy as jnp
+
+    from repro.core.engine import TaurusEngine
+
+    engine = TaurusEngine.from_context(ctx_4bit)
+    calls = {"lut_batch": 0, "lut_batch_small": 0, "keyswitch": 0}
+    for name in calls:
+        real = getattr(engine, name)
+
+        def counted(*a, _real=real, _name=name, **kw):
+            calls[_name] += 1
+            return _real(*a, **kw)
+        setattr(engine, name, counted)
+    ic = IntegerContext.create(ctx_4bit, engine)
+    m, d = ic.spec(BITS).msg_bits, ic.spec(BITS).n_digits
+    g = trace(lambda a, b: a.radix_add(b, msg_bits=m), (2, d), (2, d))
+    xs, ys = [17, 250], [90, 9]
+    enc = [jnp.concatenate(encrypt_request_inputs(
+               ic, jax.random.key(70 + j), vals, BITS))
+           for j, vals in enumerate((xs, ys))]
+    tel = Telemetry(trace=True)
+    rt = ServeRuntime(ctx_4bit, engine, max_inflight=1, start_paused=True,
+                      telemetry=tel)
+    h = rt.submit(g, enc, client_id="A")
+    rt.resume()
+    rt.close()
+    got = decrypt_radix_output(ic, h.outputs()[0], BITS)
+    assert got == [(x + y) % 256 for x, y in zip(xs, ys)]
+    return SimpleNamespace(tel=tel, rt=rt, rid=h.request.request_id,
+                           calls=calls, events=tel.recorder.events())
+
+
+def test_span_ids_parents_and_request_follow_the_radix_fanout(
+        traced_fanout):
+    events, rid = traced_fanout.events, traced_fanout.rid
+    by_id = {e.id: e for e in events}
+    assert len(by_id) == len(events)                  # ids are unique
+    req = next(e for e in events if e.name == "request")
+    assert req.args["request"] == rid and req.parent is None
+    fan = [e for e in events if e.tid != req.tid and e.dur is not None
+           and e.name in ("radix_linear", "lut_encode", "await_rows",
+                          "row_keys", "pbs_round", "barrier_wait",
+                          "fused_round")]
+    assert {e.tid for e in fan}, "no spans on the fan-out threads"
+    assert len({e.tid for e in fan}) == 2             # two digit vectors
+    for e in fan:
+        # every span of the fan-out names the request and descends from
+        # its span, through the adopted cause
+        assert e.args["request"] == rid, e
+        p = e
+        while p.parent is not None:
+            p = by_id[p.parent]
+        assert p is req, e
+    # the outermost span of each fan-out thread has the request span as
+    # its parent: the cause crossed the thread boundary
+    for tid in {e.tid for e in fan}:
+        first = min((e for e in fan if e.tid == tid), key=lambda e: e.ts)
+        assert by_id[first.parent].tid == req.tid
+    # round ids link each pbs_round to one fused_round, and each
+    # engine_room to the fused_round that enqueued it (its parent)
+    fused = {e.args["round"]: e for e in events if e.name == "fused_round"}
+    for e in events:
+        if e.name == "pbs_round":
+            assert e.args["round"] in fused
+        if e.name == "engine_room":
+            assert by_id[e.parent] is fused[e.args["round"]]
+    # the Chrome export carries them
+    x = [ev for ev in traced_fanout.tel.chrome_trace()["traceEvents"]
+         if ev["ph"] == "X"]
+    assert all("id" in ev["args"] and "parent" in ev["args"] for ev in x)
+
+
+def test_one_engine_room_span_per_engine_room_call(traced_fanout):
+    events, calls = traced_fanout.events, traced_fanout.calls
+    rooms = [e for e in events if e.name == "engine_room"]
+    assert len(rooms) == sum(calls.values()) > 0
+    progs = [e.args["program"] for e in rooms]
+    assert progs.count("keyswitch_batch_jit") == calls["keyswitch"]
+    assert progs.count("pbs_batch_small") == calls["lut_batch_small"]
+    assert progs.count("pbs_batch") == calls["lut_batch"]
+    # the spans of the device's watcher lane never overlap, and they are
+    # what the scheduler dispatched, row for row
+    assert {e.thread for e in rooms} == {"engine-room cpu:0"}
+    rooms.sort(key=lambda e: e.ts)
+    for a, b in zip(rooms, rooms[1:]):
+        assert b.ts >= a.ts + a.dur
+    assert all(e.dur > 0 and e.args["queued_ms"] >= 0 for e in rooms)
+    c = traced_fanout.rt.metrics()["counters"]
+    assert sum(e.args["padded"] for e in rooms
+               if e.args["program"].startswith("pbs_batch")) \
+        == c["sched.padded_luts"]
+    assert sum(e.args["rows"] for e in rooms
+               if e.args["program"].startswith("pbs_batch")) \
+        == c["sched.dispatched_luts"]
+    assert validate_chrome_trace(traced_fanout.tel.chrome_trace()) > 0
+
+
+def test_tracing_off_starts_no_watcher(ctx_2bit, engine_2bit):
+    mod = ctx_2bit.params.plaintext_modulus
+    table = np.array([(3 * v + 1) % mod for v in range(mod)])
+    g = trace(lambda x: (x + np.array([1, 0, 1, 0])).lut(table), (4,))
+    x = ctx_2bit.encrypt(jax.random.key(5), np.array([0, 1, 2, 1]))
+
+    def wave(tel):
+        before = set(threading.enumerate())
+        rt = ServeRuntime(ctx_2bit, engine_2bit, max_inflight=1,
+                          telemetry=tel)
+        h = rt.submit(g, [x], client_id="A")
+        jax.block_until_ready(h.outputs())
+        rt.close()
+        return [t for t in set(threading.enumerate()) - before
+                if t.name.startswith("engine-room")]
+
+    off = Telemetry()
+    assert wave(off) == []
+    assert off.recorder.events() == []
+    assert off.snapshot()["counters"]["sched.fused_rounds"] > 0
+    on = Telemetry(trace=True)
+    assert wave(on)                     # the same wave, traced, starts one
+    names = {e.name for e in on.recorder.events()}
+    assert {"radix_linear", "lut_encode", "engine_room"} <= names
+
+
+def test_spans_sit_on_the_profiles_host_plane(tmp_path):
+    """Each span() also enters a TraceAnnotation: a JAX profile taken on
+    the CPU holds the program's span names on its host plane, and a
+    backfilled record() does not."""
+    import glob
+
+    tel = Telemetry(trace=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tel.span("probe_outer", cat="t"):
+            with tel.span("probe_inner", cat="t"):
+                jax.block_until_ready(jax.numpy.ones(4) + 1)
+        tel.record("probe_backfill", "t", time.perf_counter() - 1e-3, 1e-3)
+        with Telemetry().span("probe_untraced"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = {ev.name for plane in pd.planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events}
+    assert {"probe_outer", "probe_inner"} <= host
+    assert "probe_backfill" not in host and "probe_untraced" not in host
+
+
+def test_forced_compile_counts_and_records_a_compile_span():
+    tel = Telemetry(trace=True)
+    assert tel.snapshot()["counters"]["jit.compiles"] == 0
+    salt = time.perf_counter_ns() % 1_000_003        # a program never seen
+    with tel.span("outer", cat="t", request=7) as sp:
+        jax.jit(lambda v: v * 3 + salt)(jax.numpy.arange(5))
+    c = tel.snapshot()["counters"]
+    assert c["jit.compiles"] >= 1
+    comp = [e for e in tel.recorder.spans() if e.name == "compile"]
+    assert len(comp) == c["jit.compiles"]
+    outer = next(e for e in tel.recorder.spans() if e.name == "outer")
+    for e in comp:
+        # on the compiling thread, inside the span that compiled
+        assert e.tid == outer.tid and e.parent == sp.id
+        assert e.args["request"] == 7
+        assert outer.ts <= e.ts and e.ts + e.dur <= outer.ts + outer.dur
+
+
+def test_watcher_records_every_execution_under_contention(monkeypatch):
+    """Many threads hand executions to one recorder's watcher at once,
+    with a short thread switch interval and a watcher thread that exits
+    when idle for 1 ms and starts again: every execution is recorded
+    once, and the device lane's spans never overlap."""
+    from repro.obs import watch_execution, watcher
+
+    monkeypatch.setattr(watcher, "LINGER_S", 0.001)
+    tel = Telemetry(trace=True)
+    n_threads, per_thread = 16, 25
+
+    def work(i):
+        for j in range(per_thread):
+            with tel.span("fused_round", cat="sched", round=i * 100 + j):
+                out = jax.numpy.full((4,), i * 100 + j)
+                watch_execution(out, program="p", rows=4, padded=4)
+            if j % 5 == 0:
+                time.sleep(0.003)              # let the watcher go idle
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        rooms = [e for e in tel.recorder.spans() if e.name == "engine_room"]
+    finally:
+        sys.setswitchinterval(old)
+    assert len(rooms) == n_threads * per_thread
+    assert sorted(e.args["round"] for e in rooms) == sorted(
+        i * 100 + j for i in range(n_threads) for j in range(per_thread))
+    rooms.sort(key=lambda e: e.ts)
+    for a, b in zip(rooms, rooms[1:]):
+        assert b.ts >= a.ts + a.dur
+    assert validate_chrome_trace(tel.chrome_trace()) > 0
