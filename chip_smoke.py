@@ -9,12 +9,12 @@ One process, no CPU fallback: exits non-zero, printing no result, when
 JAX finds no TPU or the repository's `src/` is missing.  The one-chip run
 
   1. prints the JAX / libtpu versions and the device kind;
-  2. measures float64 precision on the chip: the largest error, in
-     bits, of a transform-domain negacyclic product (gadget digits times
-     a torus polynomial, N = 2048) against the exact integer product
-     computed on the host, at test-4bit's and cnn20's decomposition
-     base; the error must stay below the decrypt margin Delta/2;
-  3. generates TEST_PARAMS_4BIT keys on the chip;
+  2. checks the TPU's engine room on the chip: the int8 external
+     product (gadget digits times a GGSW, N = 2048) must equal the exact
+     integer product computed on the host bit for bit, at test-4bit's
+     and cnn20's decomposition;
+  3. generates TEST_PARAMS_4BIT keys on the chip, whose BSK must come
+     out in the int8 block layout;
   4. serves two waves of clients through `Session(ctx, backend="serve")`
      — 16-bit radix add, mul and relu, and the radix GPT-2 block — and
      decrypts every output against its plaintext oracle;
@@ -58,37 +58,39 @@ def exact_negacyclic(dig, tor):
         axis=1, dtype=np.uint64)
 
 
-def f64_probe(seed: int) -> dict:
-    """Largest error (bits) of fft.negacyclic_mul on the device against
-    the exact product, per decomposition base; asserts each fits the
-    parameter set's decrypt margin Delta/2."""
+def int8_probe(seed: int) -> dict:
+    """The int8 external product on the device against the exact
+    product on the host, per decomposition; raises unless bit-identical."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.core import fft
+    from repro.core import blockmul
     from repro.core.params import PAPER_PARAMS, TEST_PARAMS_4BIT
 
     rng = np.random.default_rng(seed)
-    mul = jax.jit(fft.negacyclic_mul)
     out = {}
     for p in (TEST_PARAMS_4BIT, PAPER_PARAMS["cnn20"]):
-        half = 1 << (p.pbs_base_log - 1)
-        dig = rng.integers(-half, half, (4, 2048))
-        tor = rng.integers(0, 2 ** 64, (4, 2048), dtype=np.uint64)
-        got = np.asarray(mul(jnp.asarray(dig), jnp.asarray(tor)))
-        err = max(float(np.max(np.abs((got[i] - exact_negacyclic(
-            dig[i], tor[i])).astype(np.int64).astype(np.float64))))
-            for i in range(len(dig)))
-        bits = float(np.log2(err + 1.0))
-        margin = p.delta.bit_length() - 2               # log2(Delta / 2)
-        out[p.name] = {"base_log": p.pbs_base_log, "err_bits": bits,
-                       "margin_bits": margin}
-        log(f"f64 probe {p.name}: base_log={p.pbs_base_log} N=2048 "
-            f"max |error| = 2^{bits:.2f} (decrypt margin 2^{margin})")
-        if not bits < margin:
-            raise AssertionError(
-                f"f64 transform error 2^{bits:.2f} exceeds the decrypt "
-                f"margin 2^{margin} at {p.name}")
+        K, J, half = p.k + 1, (p.k + 1) * p.pbs_level, 1 << (p.pbs_base_log - 1)
+        g = rng.integers(0, 2 ** 64, (K, p.pbs_level, K, 2048),
+                         dtype=np.uint64)
+        dig = rng.integers(-half, half + 1, (2, J, 2048))
+        dig[0, :, ::2] = -half
+        got = np.asarray(jax.jit(
+            lambda g, d: blockmul.external_product(
+                blockmul.ggsw_to_blocks(g), d, K, p.pbs_base_log))(
+            jnp.asarray(g), jnp.asarray(dig)))
+        S = g.reshape(J, K, 2048)
+        want = np.zeros_like(got)
+        for b in range(2):
+            for k in range(K):
+                for j in range(J):
+                    want[b, k] += exact_negacyclic(dig[b, j], S[j, k])
+        out[p.name] = bool(np.array_equal(got, want))
+        log(f"int8 probe {p.name}: base_log={p.pbs_base_log} "
+            f"level={p.pbs_level} N=2048 exact: {out[p.name]}")
+        if not out[p.name]:
+            raise AssertionError(f"the int8 external product is not exact "
+                                 f"at {p.name}")
     return out
 
 
@@ -163,14 +165,18 @@ def one_chip(ctx_key: int) -> None:
     import jax
     import numpy as np
     from repro.api import Session
+    from repro.core import ggsw
     from repro.core.params import TEST_PARAMS_4BIT
     from repro.core.pbs import TFHEContext
     from repro.obs import Telemetry
 
-    f64_probe(ctx_key)
+    int8_probe(ctx_key)
     t0 = time.perf_counter()
     ctx = TFHEContext.create(jax.random.key(ctx_key), TEST_PARAMS_4BIT)
     jax.block_until_ready((ctx.bsk_f, ctx.ksk))
+    if ggsw.key_layout(ctx.bsk_f) != "int8_blocks":
+        raise AssertionError(f"the chip's BSK is {ctx.bsk_f.dtype}, not "
+                             f"the int8 block layout")
     log(f"keygen {TEST_PARAMS_4BIT.name} on {ctx.bsk_f.devices()}: "
         f"{time.perf_counter() - t0:.3f} s (compile included); bsk_f "
         f"{ctx.bsk_f.shape} {ctx.bsk_f.dtype}, ksk {ctx.ksk.shape} "

@@ -3,8 +3,9 @@
 Taurus's BRU round-robins 12 ciphertexts through one wide FFT pipeline so
 each BSK chunk streamed from HBM is consumed by ALL in-flight ciphertexts.
 On TPU the same insight is a BATCH dimension: one blind-rotation iteration
-loads bsk_f[i] once and applies it to the whole ciphertext batch via a
-single transform-domain MAC.  Arithmetic intensity on the
+loads bsk_f[i] once and applies it to the whole ciphertext batch in one
+external product (on a TPU, one int8 matmul whose rows are the batch's
+digit blocks).  Arithmetic intensity on the
 BSK stream scales linearly with the batch size, exactly the paper's Fig. 13
 bandwidth argument.
 
@@ -35,7 +36,8 @@ def blind_rotate_batch(lut_glwes: jax.Array, ms_cts: jax.Array,
     """Batched blind rotation.
 
     lut_glwes: (B, k+1, N); ms_cts: (B, n+1) mod-switched to [0, 2N);
-    bsk_f: (n, 2, J, K, N/2) planes — scanned once, shared across batch.
+    bsk_f: the BSK in `ggsw.bsk_to_fourier`'s layout — scanned once,
+    shared across batch.
     """
     N = params.N
     a, b = ms_cts[:, :-1], ms_cts[:, -1]
@@ -43,8 +45,8 @@ def blind_rotate_batch(lut_glwes: jax.Array, ms_cts: jax.Array,
 
     def step(acc, inp):
         a_i, bsk_i = inp                                # a_i: (B,)
-        rotated = rotate_batch(acc, a_i, N)
-        diff = rotated - acc
+        with jax.named_scope("rotate"):
+            diff = rotate_batch(acc, a_i, N) - acc
         # one GGSW applied to the whole batch: the key-reuse MAC
         acc = acc + ggsw.external_product_fourier(
             bsk_i, diff, params.pbs_base_log, params.pbs_level

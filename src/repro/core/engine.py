@@ -11,10 +11,10 @@ The engine is the execution backend for `repro.compiler` schedules and
 the unit benchmarks in `benchmarks/`.
 
 Kernel backends: `kernel_backend="reference"` (default) runs the jax
-PBS in `repro.core.batch` — float64 re/im planes and uint64 integer
-arithmetic only, the engine room XLA:TPU compiles and the one every
-platform runs; `"pallas"` runs the fused Pallas engine room
-(`repro.kernels.fused_pbs`) — same KS-first pipeline, but the FFT /
+PBS in `repro.core.batch`, whose CMux steps follow the BSK layout
+`ggsw.bsk_to_fourier` built for the platform (exact int8 matmuls on a
+TPU, float64 re/im planes elsewhere); `"pallas"` runs the fused Pallas
+engine room (`repro.kernels.fused_pbs`) — same KS-first pipeline, but the FFT /
 external-product / keyswitch stages execute as Pallas kernels against a
 `FusedPbsPack` of resident transform-domain key operands (built lazily
 on first `lut_batch`, reused across every round — the paper's key-reuse
@@ -27,8 +27,8 @@ Timing: each engine-room execution is handed to
 tracing `Telemetry` (the scheduler's `fused_round`, a request's span)
 the telemetry's watcher records it as an `engine_room` span: its busy
 interval on the device, the program's name as the device trace prints
-it, its real and dispatched rows.  Otherwise that costs one thread-local
-lookup.
+it, its real and dispatched rows and the layout of its bootstrapping
+key (`ggsw.key_layout`).  Otherwise that costs one thread-local lookup.
 """
 from __future__ import annotations
 
@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.core import batch as batch_mod, glwe, lwe, torus
+from repro.core import batch as batch_mod, ggsw, glwe, lwe, torus
 from repro.core.params import TFHEParams
 from repro.obs.trace import watch_execution
 
@@ -63,8 +63,8 @@ class ConfigError(ValueError):
     raising here (see `repro.serve.shard.build_shards`).
 
     pallas on a TPU is NOT supported either: Mosaic has no float64, and
-    float32 transform planes lack the precision of the 64-bit torus
-    until the split fixed-point planes of ROADMAP S2 land."""
+    float32 transform planes lack the precision of the 64-bit torus;
+    the reference backend's int8 room is the TPU's."""
 
 
 def validate_lut_tables(cts: jax.Array, tables, params: TFHEParams):
@@ -114,9 +114,8 @@ class TaurusEngine:
             raise ConfigError(
                 "kernel_backend='pallas' is not supported on TPU: Mosaic "
                 "has no float64, and float32 transform planes lack the "
-                "precision of the 64-bit torus until the split fixed-point "
-                "planes of ROADMAP S2 land. Use the reference backend, "
-                "which runs float64 planes through XLA.")
+                "precision of the 64-bit torus. Use the reference "
+                "backend, which runs exact int8 matmuls on the MXU.")
         if self.mesh is not None and self.device is not None:
             raise ConfigError("pass mesh OR device, not both")
         if self.kernel_backend == "pallas" and self.mesh is not None:
@@ -256,7 +255,8 @@ class TaurusEngine:
             out = self._mesh_pbs(cts, lut_polys, self.bsk_f, self.ksk,
                                  self.params)
             program = "pbs_batch"
-        watch_execution(out, program=program, rows=rows, padded=B + pad)
+        watch_execution(out, program=program, rows=rows, padded=B + pad,
+                        layout=ggsw.key_layout(self.bsk_f))
         if self.mesh is not None:
             # hand the round back on one device: request threads
             # then run single-device ops only, never concurrent
@@ -290,7 +290,8 @@ class TaurusEngine:
             out = batch_mod.keyswitch_batch_jit(big_cts, self.ksk,
                                                 self.params)
             program = "keyswitch_batch_jit"
-        watch_execution(out, program=program, rows=rows, padded=B)
+        watch_execution(out, program=program, rows=rows, padded=B,
+                        layout=ggsw.key_layout(self.bsk_f))
         return out
 
     def lut_batch_small(self, small_cts: jax.Array, lut_polys: jax.Array,
@@ -319,7 +320,8 @@ class TaurusEngine:
             out = batch_mod.pbs_batch_small(small_cts, lut_polys,
                                             self.bsk_f, self.params)
             program = "pbs_batch_small"
-        watch_execution(out, program=program, rows=rows, padded=B)
+        watch_execution(out, program=program, rows=rows, padded=B,
+                        layout=ggsw.key_layout(self.bsk_f))
         return out
 
     def lut_batch_tables(self, cts: jax.Array, tables) -> jax.Array:

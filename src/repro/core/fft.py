@@ -23,6 +23,11 @@ relative precision: ample for the external product, whose operands are
 gadget digits times torus values (error budget in
 `tests/test_core_fft.py`).
 
+This transform is the external product's engine room on every platform
+but the TPU, and the oracle the TPU's room is tested against; on a TPU
+the CMux step runs as exact int8 matmuls (`repro.core.blockmul`), since
+emulated float64 runs about 10^5 times below the chip's peak.
+
 `negacyclic_mul_exact` is the exact variant for keys: the torus operand
 is split into 16-bit limbs, so every limb product is an integer far
 below 2^53 that the transform returns exactly after rounding.

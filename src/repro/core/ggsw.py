@@ -4,19 +4,24 @@ A GGSW ciphertext of a bit s is a ((k+1)*level, k+1, N) stack of GLWE
 rows:  row (u, l) = GLWE_sk(0) + s * g_l * e_u   (Z + s*G).
 
 The external product  GGSW ⊡ GLWE -> GLWE  (paper Fig. 4b) is a
-vector-matrix product over polynomials in the transform domain.  Its
-Pallas incarnation, `repro.kernels.external_product`, runs inside the
-engine's fused PBS path (`TaurusEngine(kernel_backend="pallas")` ->
-`repro.kernels.fused_pbs`) against the same plane-layout BSK that
-`bsk_to_fourier` builds; this module is the engine room every platform
-runs by default and the differential-test oracle.
+vector-matrix product over polynomials.  `bsk_to_fourier` lays the BSK
+out once for the platform's engine room, and `external_product_fourier`,
+which every CMux step calls, follows the layout it is handed:
+
+- int8 blocks (a TPU, N <= 4096): exact int8 x int8 -> int32 matmuls on
+  the MXU (`repro.core.blockmul`);
+- float64 (re, im) planes (every other platform): the negacyclic
+  transform of `repro.core.fft` and a transform-domain MAC.  This is the
+  CPU room and the differential-test oracle; the Pallas room
+  (`TaurusEngine(kernel_backend="pallas")` -> `repro.kernels.fused_pbs`,
+  CPU only) reads the same planes.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.core import fft, glwe, decompose as dec
+from repro.core import blockmul, fft, glwe, decompose as dec
 from repro.core.params import TFHEParams
 
 U64 = jnp.uint64
@@ -66,26 +71,45 @@ def ggsw_to_fourier(ggsw_ct: jax.Array) -> jax.Array:
     return jnp.moveaxis(f, 2, 0)
 
 
+def int8_room(N: int, J: int) -> bool:
+    """Whether this platform's engine room reads int8 blocks: on a TPU,
+    where the key fits a chip and any digit width sums exactly."""
+    return (jax.default_backend() == "tpu" and N <= blockmul.MAX_N
+            and blockmul.exact(N, J))
+
+
 def bsk_to_fourier(bsk: jax.Array) -> jax.Array:
-    """Pre-transform the BSK once: (n, 2, J, K, M) float64 planes.
+    """Lay the BSK out once for the platform's engine room.
 
     This is the stream the paper's BRU reads from HBM; in the batched
-    engine it is the reused operand (key-reuse strategy, §III-B).  The
-    Pallas engine room reads the same layout (`kernels.fused_pbs`).
+    engine it is the reused operand (key-reuse strategy, §III-B).
+    (n, N*J, 8*K*b) int8 blocks where `int8_room`, else (n, 2, J, K, M)
+    float64 planes.
     """
+    _, kp1, level, _, N = bsk.shape
+    if int8_room(N, kp1 * level):
+        return blockmul.bsk_to_blocks(bsk)
     return jax.vmap(ggsw_to_fourier)(bsk)
+
+
+def key_layout(bsk_f: jax.Array) -> str:
+    """The name of the layout `bsk_to_fourier` built."""
+    return "int8_blocks" if bsk_f.dtype == jnp.int8 else "f64_planes"
 
 
 def external_product_fourier(ggsw_f: jax.Array, glwe_ct: jax.Array,
                              base_log: int, level: int) -> jax.Array:
-    """GGSW (planes, (2, J, K, M)) ⊡ GLWE ((k+1, N)) -> GLWE.
-
-    Batched over leading axes of `glwe_ct`.
-    """
+    """GGSW (one step of `bsk_to_fourier`'s layout) ⊡ GLWE ((k+1, N))
+    -> GLWE, batched over leading axes of `glwe_ct`.  int8 blocks take
+    the integer product, float64 planes the transform."""
     *lead, kp1, N = glwe_ct.shape
-    digits = dec.decompose(glwe_ct, base_log, level)     # (..., k+1, N, level)
-    digits = jnp.moveaxis(digits, -1, -2)                # (..., k+1, level, N)
-    dig_f = fft.forward(digits.reshape(*lead, kp1 * level, N))
+    with jax.named_scope("decompose"):
+        digits = dec.decompose(glwe_ct, base_log, level)  # (..., k+1, N, level)
+        digits = jnp.moveaxis(digits, -1, -2)             # (..., k+1, level, N)
+        digits = digits.reshape(*lead, kp1 * level, N)
+    if ggsw_f.dtype == jnp.int8:
+        return blockmul.external_product(ggsw_f, digits, kp1, base_log)
+    dig_f = fft.forward(digits)
     return fft.inverse_torus(fft.mac(dig_f, ggsw_f))     # (..., k+1, N)
 
 

@@ -31,7 +31,7 @@ def blind_rotate(lut_glwe: jax.Array, lwe_ct_mod: jax.Array,
 
     lut_glwe: (k+1, N) trivial/encrypted GLWE holding the LUT.
     lwe_ct_mod: (n+1,) uint64 values already mod-switched into [0, 2N).
-    bsk_f: (n, 2, J, K, N/2) plane-layout fourier BSK.
+    bsk_f: the BSK in `ggsw.bsk_to_fourier`'s layout.
     """
     N = params.N
     a, b = lwe_ct_mod[:-1], lwe_ct_mod[-1]
@@ -87,7 +87,7 @@ class TFHEContext:
     lwe_sk: jax.Array      # small key (n,)
     glwe_sk: jax.Array     # (k, N)
     big_sk: jax.Array      # flattened GLWE key (k*N,)
-    bsk_f: jax.Array       # fourier bootstrapping key, f64 planes (eval key)
+    bsk_f: jax.Array       # bootstrapping key, `ggsw.bsk_to_fourier` layout
     ksk: jax.Array         # key-switching key big->small (server/eval key)
 
     @classmethod

@@ -181,8 +181,9 @@ class PbsRoundModel:
 def pbs_round_model(params, batch: int) -> PbsRoundModel:
     """Build the per-round bandwidth model from TFHE parameters.
 
-    Key bytes match `TaurusEngine.key_bytes` exactly: the Fourier BSK is
-    (n, 2, (k+1)*level, k+1, N/2) float64 planes and the KSK is
+    Key bytes match `TaurusEngine.key_bytes` off the TPU, exactly: the
+    Fourier BSK is (n, 2, (k+1)*level, k+1, N/2) float64 planes (a TPU
+    holds int8 blocks instead, `core/blockmul.py`) and the KSK is
     (big_n, ks_level, n+1) uint64 — the fused pack's f64 planes and
     (hi, lo) uint32 limbs hold the same bytes, so reference and pallas
     engines share one model.

@@ -637,6 +637,13 @@ def test_one_engine_room_span_per_engine_room_call(traced_fanout):
     assert validate_chrome_trace(traced_fanout.tel.chrome_trace()) > 0
 
 
+def test_engine_room_spans_name_the_key_layout(traced_fanout):
+    """Each execution names the BSK layout it ran against: f64 planes on
+    the CPU (int8 blocks on a TPU)."""
+    rooms = [e for e in traced_fanout.events if e.name == "engine_room"]
+    assert rooms and {e.args["layout"] for e in rooms} == {"f64_planes"}
+
+
 def test_tracing_off_starts_no_watcher(ctx_2bit, engine_2bit):
     mod = ctx_2bit.params.plaintext_modulus
     table = np.array([(3 * v + 1) % mod for v in range(mod)])

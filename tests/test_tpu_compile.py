@@ -9,6 +9,12 @@ TPU compiler that ships with libtpu.  Each program's jaxpr is checked
 for complex values and uint64 dots BEFORE it is compiled, so a
 regression fails here with a message instead of a SIGABRT.
 
+On a TPU the engine room reads the BSK as int8 blocks
+(`repro.core.blockmul`); keygen here runs on the CPU backend and builds
+f64 planes, so the int8 cases build the TPU layout's shapes themselves
+and take the TPU's convolution (`blockmul.correlate_conv`), which the
+CPU backend would not choose.
+
 The topology is described inside a module fixture (never at import:
 only one process at a time may load libtpu, and every test worker
 imports this file), and the persistent compile cache is off in this
@@ -21,12 +27,17 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import batch, pbs
-from repro.core.params import PAPER_PARAMS, TEST_PARAMS_4BIT
+from repro.core import batch, blockmul, pbs
+from repro.core.params import PAPER_PARAMS, TEST_PARAMS_4BIT, TFHEParams
 from repro.kernels import external_product, fourstep_fft, keyswitch
 
 B = 12                       # the engine's fused round width per device
 HBM_BYTES = 16 * 10 ** 9     # one v5e chip
+# TFHE-rs's PARAM_MESSAGE_2_CARRY_2_KS_PBS, the benchmark's parameter set
+MSG2CARRY2 = TFHEParams(
+    name="msg2carry2", n=742, N=2048, k=1, width=4, pbs_base_log=23,
+    pbs_level=1, ks_base_log=3, ks_level=5, lwe_std=7.069849454709433e-06,
+    glwe_std=2.9403601535432533e-16)
 
 
 @pytest.fixture(scope="module")
@@ -69,6 +80,20 @@ def _engine_args(params, sharding):
     return _on(sharding, dict(
         cts=u64(B, params.big_n + 1), small=u64(B, params.n + 1),
         polys=u64(B, params.N), bsk_f=bsk_f, ksk=ksk))
+
+
+def _int8_args(params, rows, sharding):
+    """Round inputs and the TPU's keys: the BSK as int8 blocks."""
+    bsk = jax.ShapeDtypeStruct(
+        (params.n, params.k + 1, params.pbs_level, params.k + 1, params.N),
+        jnp.uint64)
+    blocks = jax.eval_shape(blockmul.bsk_to_blocks, bsk)
+    ksk = jax.eval_shape(lambda k: pbs._keygen(k, params),
+                         jax.random.key(0))[4]
+    u64 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.uint64)
+    return _on(sharding, dict(
+        cts=u64(rows, params.big_n + 1), small=u64(rows, params.n + 1),
+        polys=u64(rows, params.N), bsk_f=blocks, ksk=ksk))
 
 
 def _walk(jaxpr):
@@ -156,3 +181,41 @@ def test_pallas_kernels_compile_f32_n2048(one_chip):
     for fn, args in cases:
         _, hlo = _compile(fn, *args)
         assert "tpu_custom_call" in hlo, "kernel did not lower to Mosaic"
+
+
+def _assert_int8_loop(fn, *args):
+    """Inside the blind-rotation loop: no float64 value, no float64 or
+    uint64 dot, and an int8 x int8 product."""
+    closed = jax.make_jaxpr(fn)(*args)
+    loops = [e for e in _walk(closed.jaxpr) if e.primitive.name == "scan"]
+    assert loops, "no blind-rotation loop"
+    products = []
+    for eqn in _walk(loops[0].params["jaxpr"].jaxpr):
+        for v in list(eqn.invars) + list(eqn.outvars):
+            dt = getattr(v.aval, "dtype", None)
+            assert dt != jnp.float64, f"{eqn.primitive} carries float64"
+        if eqn.primitive.name in ("dot_general", "conv_general_dilated"):
+            dts = {v.aval.dtype for v in eqn.invars}
+            assert dts == {jnp.dtype(jnp.int8)}, f"{eqn.primitive} over {dts}"
+            products.append(eqn)
+    assert products, "no int8 product in the loop"
+
+
+@pytest.mark.parametrize("rows", [16, 96])
+@pytest.mark.parametrize("entry", ["lut_batch", "lut_batch_small"])
+def test_int8_engine_room_compiles_msg2carry2(one_chip, monkeypatch, entry,
+                                               rows):
+    monkeypatch.setattr(blockmul, "correlate", blockmul.correlate_conv)
+    p = MSG2CARRY2
+    a = _int8_args(p, rows, one_chip)
+    fn, args = {
+        "lut_batch": (lambda c, l, b, k: batch.pbs_batch(c, l, b, k, p),
+                      (a["cts"], a["polys"], a["bsk_f"], a["ksk"])),
+        "lut_batch_small": (lambda c, l, b: batch.pbs_batch_small(c, l, b, p),
+                            (a["small"], a["polys"], a["bsk_f"])),
+    }[entry]
+    _assert_int8_loop(fn, *args)
+    compiled, hlo = _compile(fn, *args)
+    assert "s8[" in hlo and "convolution(" in hlo
+    used = _device_bytes(compiled)
+    assert used < HBM_BYTES, f"msg2carry2 round needs {used} B"
